@@ -63,6 +63,8 @@ from repro.core.engine import StreamEngine                    # noqa: E402
 from repro.core.registry import Registry                      # noqa: E402
 from repro.launch import chaos as C                           # noqa: E402
 from repro.launch.supervise import Supervisor                 # noqa: E402
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
 SEED = 11
 
@@ -159,15 +161,17 @@ def bench_shards(n_shards: int, n_tenants: int, n_steps: int, K: int) -> dict:
     eng, flows = _build(n_tenants, n_shards, 0)
     sids = [f[1].sid for f in flows]
     clean_feed = _make_feed(sids, n_steps, 1, [], SEED)
+    scan0 = compile_count(eng._superstep_fn(K))
     eng.superstep(K)                       # warm-up: compile the K-scan
     dt_armed = _run_plain(eng, clean_feed, n_steps, K)
     clean_emitted = _tenant_emitted(eng)
-    retraces += eng._superstep_fns[K]._cache_size() - 1
+    retraces += compile_count(eng._superstep_fns[K]) - scan0 - 1
     eng2, _ = _build(n_tenants, n_shards, 0)
     eng2.set_breaker(threshold=0, amp_ceiling=0)      # disarmed, same XLA
+    scan0 = compile_count(eng2._superstep_fn(K))
     eng2.superstep(K)
     dt_off = _run_plain(eng2, clean_feed, n_steps, K)
-    retraces += eng2._superstep_fns[K]._cache_size() - 1
+    retraces += compile_count(eng2._superstep_fns[K]) - scan0 - 1
     res["overhead"] = {
         "armed_steps_per_s": n_steps / dt_armed,
         "disarmed_steps_per_s": n_steps / dt_off,
@@ -180,8 +184,9 @@ def bench_shards(n_shards: int, n_tenants: int, n_steps: int, K: int) -> dict:
     # round-for-round for the bit-exactness check.
     feed = _make_feed(sids, n_steps, 1, poison_steps, SEED)
     twin, _ = _build(n_tenants, n_shards, 0)
+    scan0 = compile_count(twin._superstep_fn(K))
     _run_plain(twin, feed, n_steps, K)
-    retraces += twin._superstep_fns[K]._cache_size() - 1
+    retraces += compile_count(twin._superstep_fns[K]) - scan0 - 1
     twin_arrays, _ = twin.snapshot()
     twin_emitted = _tenant_emitted(twin)
     fc = twin.fault_counters()
@@ -200,9 +205,12 @@ def bench_shards(n_shards: int, n_tenants: int, n_steps: int, K: int) -> dict:
 
         sup = Supervisor(eng3, ckdir, feed=feed, chaos=chaos_hook, K=K,
                          escalate_after=10**9)   # observational blame only
+        scan0 = compile_count(eng3._superstep_fn(K))
         report = sup.run(n_steps)
         final = sup.engine
-        retraces += final._superstep_fns[K]._cache_size() - 1
+        # the first engine and each restored one compile the scan once
+        retraces += compile_count(final._superstep_fns[K]) - scan0 \
+            - (1 + len(report.incidents))
         if final._ckpt is not None:
             final._ckpt.wait()
         chaos_arrays, _ = final.snapshot()
@@ -256,6 +264,7 @@ def bench(n_tenants: int, n_steps: int, K: int, shard_counts) -> dict:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", type=int, default=6)
     ap.add_argument("--steps", type=int, default=40)

@@ -43,6 +43,8 @@ import numpy as np                                            # noqa: E402
 import jax                                                    # noqa: E402
 
 from repro.core import EngineConfig, Registry, create_engine  # noqa: E402
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
 
 def _build(n_nodes: int, n_shards: int, spare: int):
@@ -87,7 +89,7 @@ def bench(n_nodes: int, n_rounds: int, n_shards: int, churn_every: int = 1,
     eng.revoke_subscription(warm, sources[-1])
     eng.revoke_stream(warm)
     eng.round()
-    cache0 = eng._step._cache_size()
+    cache0 = compile_count(eng._step)
 
     # ---- admit / revoke latency -----------------------------------------
     admit_ms, revoke_ms = [], []
@@ -123,7 +125,7 @@ def bench(n_nodes: int, n_rounds: int, n_shards: int, churn_every: int = 1,
         ts += 1
     jax.block_until_ready(eng.state.timestamps)
     dt_churn = time.perf_counter() - t0
-    retraces = eng._step._cache_size() - cache0
+    retraces = compile_count(eng._step) - cache0
 
     # ---- rounds/s static baseline (same SU load, no churn) --------------
     t0 = time.perf_counter()
@@ -166,6 +168,7 @@ def bench(n_nodes: int, n_rounds: int, n_shards: int, churn_every: int = 1,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=96)
     ap.add_argument("--rounds", type=int, default=50)

@@ -55,6 +55,8 @@ import jax                                                    # noqa: E402
 from repro.checkpoint.ckpt import CheckpointManager           # noqa: E402
 from repro.core import (EngineConfig, Registry, create_engine,  # noqa: E402
                         restore_engine)
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
 
 def _build(n_chains: int, depth: int, n_shards: int, checkpoint_every: int):
@@ -120,7 +122,7 @@ def bench(rounds: int, n_chains: int, depth: int, n_shards: int,
     eng.redeliver()
     eng.drain()
     jax.block_until_ready(eng.state.timestamps)
-    cache0 = eng._step._cache_size()
+    cache0 = compile_count(eng._step)
 
     # ---- timed: plain loaded rounds vs checkpointed loaded rounds
     def timed_rounds(n):
@@ -175,7 +177,7 @@ def bench(rounds: int, n_chains: int, depth: int, n_shards: int,
         redeliver_ms.append(1e3 * (time.perf_counter() - t0))
         eng.drain()
     jax.block_until_ready(eng.state.timestamps)
-    retraces = int(eng._step._cache_size() - cache0)
+    retraces = compile_count(eng._step) - cache0
 
     c = eng.counters()
     return {
@@ -204,6 +206,7 @@ def bench(rounds: int, n_chains: int, depth: int, n_shards: int,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=60)
     ap.add_argument("--chains", type=int, default=8)

@@ -50,15 +50,12 @@ if "xla_force_host_platform_device_count" not in _flags:
 import numpy as np                                            # noqa: E402
 
 import jax                                                    # noqa: E402
-from jax import monitoring                                    # noqa: E402
 
 from repro.core import EngineConfig, Registry, create_engine  # noqa: E402
 from repro.launch.autoscale import Autoscaler                 # noqa: E402
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
-_COMPILES = []
-monitoring.register_event_duration_secs_listener(
-    lambda name, dur, **kw: _COMPILES.append(name)
-    if name == "/jax/core/compile/backend_compile_duration" else None)
 
 
 def _build(n_chains: int, depth: int, n_shards: int):
@@ -150,7 +147,7 @@ def run_elastic(plan, n_chains, depth, max_shards, K):
     drops0, queued0 = _drops(eng)             # counter baseline post-warm
     sc = Autoscaler(eng, min_shards=1, max_shards=max_shards,
                     up=0.15, down=0.03, patience=1, cooldown=1)
-    compiles0 = len(_COMPILES)
+    compiles0 = compile_count()
     dev_s, shard_hist, resize_ms = 0.0, [], []
     t_all = time.perf_counter()
     for waves in plan:
@@ -166,7 +163,7 @@ def run_elastic(plan, n_chains, depth, max_shards, K):
             resize_ms.append(1e3 * (time.perf_counter() - t0))
             dev_s += eng.cfg.n_shards * (time.perf_counter() - t0)
     wall = time.perf_counter() - t_all
-    compiles = len(_COMPILES) - compiles0
+    compiles = compile_count() - compiles0
     drops, queued = _drops(eng)
     drops, queued = drops - drops0, queued - queued0
     return {"max_shards": max_shards, "device_seconds": dev_s,
@@ -208,6 +205,7 @@ def bench(supersteps, n_chains, depth, max_shards, K):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--supersteps", type=int, default=36)
     ap.add_argument("--chains", type=int, default=6)

@@ -54,6 +54,8 @@ from repro.models import model as M                           # noqa: E402
 from repro.serving import ContinuousBatcher                   # noqa: E402
 from repro.workloads import TraceConfig, build_suite, drive   # noqa: E402
 from repro.workloads.runner import wire_pred                  # noqa: E402
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
 KINDS = ("etl", "stats", "pred")
 SLO_ROUNDS = 16        # every tenant's latency target, in engine rounds
@@ -96,11 +98,11 @@ def bench_shards(n_shards: int, tenants: int, steps: int, K: int,
     wire_pred(suite, _make_batcher())
     eng = suite.engine
     eng.superstep(K)                       # warm-up: trace the K-scan once
-    cache0 = eng._superstep_fns[K]._cache_size()
+    cache0 = compile_count(eng._superstep_fns[K])
     t0 = time.perf_counter()
     out = drive(suite, K=K)
     dt = time.perf_counter() - t0
-    retraces = int(eng._superstep_fns[K]._cache_size() - cache0)
+    retraces = compile_count(eng._superstep_fns[K]) - cache0
     rep = out["slo_report"]
     return {
         "records": out["records"],
@@ -130,6 +132,7 @@ def bench(tenants: int, steps: int, K: int, shard_counts) -> dict:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", type=int, default=24)
     ap.add_argument("--steps", type=int, default=48)

@@ -53,6 +53,8 @@ import numpy as np                                            # noqa: E402
 import jax                                                    # noqa: E402
 
 from repro.core import EngineConfig, Registry, create_engine  # noqa: E402
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
 HEAVY_W, LIGHT_W = 8, 1          # fair-pop weights used in the on phase
 
@@ -120,7 +122,7 @@ class _Phase:
             self.eng.round()
         self.e0 = {k: v.copy() for k, v in self.eng.tenant_counters().items()}
         self.c0 = self.eng.counters()
-        self.cache0 = self.eng._step._cache_size()
+        self.cache0 = compile_count(self.eng._step)
 
     def _wave(self):
         for s in self.h_srcs:              # heavy posts first — adversarial
@@ -184,7 +186,7 @@ class _Phase:
             "heavy_dropped_quota": int(
                 (e1["dropped_quota"]
                  - self.e0["dropped_quota"])[self.heavy.tid]),
-            "retraces": int(self.eng._step._cache_size() - self.cache0),
+            "retraces": compile_count(self.eng._step) - self.cache0,
         }
 
 
@@ -228,6 +230,7 @@ def bench(rounds: int, n_heavy_src: int, fan: int, n_shards: int):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=80)
     ap.add_argument("--heavy-sources", type=int, default=8)

@@ -43,6 +43,8 @@ def bench_windows(quick: bool):
 
 
 def main():
+    from repro.launch.compiles import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="smaller sweeps (CI-sized)")

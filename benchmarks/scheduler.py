@@ -41,6 +41,8 @@ import numpy as np                                            # noqa: E402
 import jax                                                    # noqa: E402
 
 from repro.core import EngineConfig, Registry, create_engine  # noqa: E402
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
 N_SOURCES = 8           # posted every round (ingest is capped at batch)
 FAN = 8                 # L1 composites per source: the amplification
@@ -107,7 +109,7 @@ class _Phase:
             self._wave()
             self.eng.round()
         jax.block_until_ready(self.eng.state.timestamps)
-        self.cache0 = self.eng._step._cache_size()
+        self.cache0 = compile_count(self.eng._step)
 
     def _wave(self):
         for i, s in enumerate(self.srcs):
@@ -132,7 +134,7 @@ class _Phase:
             "scheduler": variant,
             "rounds_per_s": self.rounds / self.time,
             "queue_occupancy": self.occupancy(),
-            "retraces": int(self.eng._step._cache_size() - self.cache0),
+            "retraces": compile_count(self.eng._step) - self.cache0,
             "counters": {k: int(v) for k, v in self.eng.counters().items()},
         }
 
@@ -152,6 +154,7 @@ def bench_queue(queue_slots: int, rounds: int, variants):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=60,
                     help="measured rounds per (queue, scheduler) point")

@@ -32,6 +32,7 @@ import numpy as np                                            # noqa: E402
 import jax                                                    # noqa: E402
 
 from repro.core import EngineConfig, create_engine            # noqa: E402
+from repro.launch.compiles import use_compile_cache           # noqa: E402
 from benchmarks.topologies import TopoSpec, build_registry, generate  # noqa: E402
 
 
@@ -76,6 +77,7 @@ def bench_one(n_shards: int, n_nodes: int, n_rounds: int, seed: int = 0):
 
 
 def main(shard_counts=(1, 2, 4, 8), n_nodes=96, n_rounds=50):
+    use_compile_cache()
     n_dev = len(jax.devices())
     print(f"devices: {n_dev} ({jax.devices()[0].platform})")
     print(f"{'shards':>7} {'rounds/s':>10} {'emitted':>9} {'dropped':>8}")

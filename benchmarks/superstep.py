@@ -37,6 +37,8 @@ import numpy as np                                            # noqa: E402
 import jax                                                    # noqa: E402
 
 from repro.core import EngineConfig, Registry, create_engine  # noqa: E402
+from repro.launch.compiles import (compile_count,              # noqa: E402
+                                   use_compile_cache)
 
 
 def _build(n_nodes: int, n_shards: int):
@@ -84,7 +86,7 @@ def bench_one(n_nodes: int, K: int, n_shards: int, n_supersteps: int):
     ts = _post_burst(eng, sources, K, ts=1)
     eng.superstep(K)
     jax.block_until_ready(eng.state.timestamps)
-    cache0 = eng._superstep_fns[K]._cache_size()
+    cache0 = compile_count(eng._superstep_fns[K])
 
     t0 = time.perf_counter()
     for _ in range(n_supersteps):
@@ -94,7 +96,7 @@ def bench_one(n_nodes: int, K: int, n_shards: int, n_supersteps: int):
     dt = time.perf_counter() - t0
 
     c = eng.counters()
-    retraces = eng._superstep_fns[K]._cache_size() - cache0
+    retraces = compile_count(eng._superstep_fns[K]) - cache0
     return {
         "K": K, "shards": n_shards, "path": eng._path,
         "rounds_per_s": n_supersteps * K / dt,
@@ -120,6 +122,7 @@ def bench_round_api(n_nodes: int, n_shards: int, n_rounds: int):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=16)
     ap.add_argument("--supersteps", type=int, default=20,

@@ -71,7 +71,12 @@ class EngineConfig:
     # operation — a single Pallas megakernel on TPU, the pure-jnp refs
     # elsewhere.  Bit-identical to the staged round for fusable programs
     # (no transcendental opcodes); the engine checks fusability host-side
-    # at every program edit and silently uses the staged path otherwise.
+    # at every program edit and takes the staged path otherwise — the
+    # engine's ``_path`` ("fused" | "staged") names the path in use, and
+    # chip_smoke.py asserts it.  On one TPU v5e chip the megakernel runs
+    # the 1,024-tenant IoT deployment (3,586 rows, batch 256, fan-out 2)
+    # bit-identical to the staged round, within a raised scoped-VMEM
+    # limit it nearly fills (kernels/round_fuse/kernel.py).
     # Requires scheduler == "packed" (the fused pop *is* the packed pop).
     fused_round: bool = True
 

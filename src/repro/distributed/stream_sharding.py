@@ -54,12 +54,6 @@ from typing import Callable, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:                                    # jax < 0.8
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-except ImportError:                     # jax >= 0.8: graduated to jax.shard_map
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import admission
@@ -681,12 +675,15 @@ def make_shard_round(
         # a poisoned stream neither stores nor emits while tripped
         eff_active = tables.active & ~state.quarantined
         if fused:
+            # the local tables (n_local rows) and the global snapshot (N
+            # rows) are two row spaces, which the apply kernel does not
+            # take: this stage runs the fused jnp reference on every backend
             new_vals, ts_out, live, keep, keep_ts, passf, badf = \
                 apply_programs(layout, tables.in_table, tables.progs,
                                tables.consts, tables.is_composite,
                                eff_active, r_loc, rt_safe, r_src,
                                r_vals, r_ts, r_valid,
-                               values_by_sid, ts_by_sid)
+                               values_by_sid, ts_by_sid, use_kernel=False)
             stats["processed"] += live.sum(dtype=jnp.int32)
             stats["discarded_stale"] += \
                 (live & ~keep_ts).sum(dtype=jnp.int32)
@@ -753,11 +750,14 @@ def make_sharded_step(
                 jax.tree.map(lambda x: x[None], sink))
 
     sharded = P(AXIS)
-    fn = _shard_map(shard_step, mesh=mesh,
-                    in_specs=(sharded, P(), sharded, sharded),
-                    out_specs=(sharded, sharded),
-                    **_SHARD_MAP_KW)
-    return jax.jit(fn, donate_argnums=(2,) if donate else ())
+    fn = jax.shard_map(shard_step, mesh=mesh,
+                       in_specs=(sharded, P(), sharded, sharded),
+                       out_specs=(sharded, sharded), check_vma=False)
+    # pinned output sharding: zero-size leaves (retention/DLQ rings when
+    # off) would otherwise come back replicated, and the next call (or
+    # table edit) would see differently-sharded inputs
+    return jax.jit(fn, donate_argnums=(2,) if donate else (),
+                   out_shardings=NamedSharding(mesh, sharded))
 
 
 def make_sharded_superstep(
@@ -796,11 +796,12 @@ def make_sharded_superstep(
                 jax.tree.map(lambda x: x[None], ring))
 
     sharded = P(AXIS)
-    fn = _shard_map(shard_superstep, mesh=mesh,
-                    in_specs=(sharded, P(), sharded, sharded),
-                    out_specs=(sharded, sharded, sharded),
-                    **_SHARD_MAP_KW)
-    return jax.jit(fn, donate_argnums=(2, 3) if donate else ())
+    fn = jax.shard_map(shard_superstep, mesh=mesh,
+                       in_specs=(sharded, P(), sharded, sharded),
+                       out_specs=(sharded, sharded, sharded),
+                       check_vma=False)
+    return jax.jit(fn, donate_argnums=(2, 3) if donate else (),
+                   out_shardings=NamedSharding(mesh, sharded))
 
 
 # --------------------------------------------------------------------------

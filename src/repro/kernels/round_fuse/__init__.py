@@ -13,9 +13,10 @@ the barrier to stream-transform throughput.  This package pushes the
   kernel on TPU (winners stay in VMEM from the pop until their window
   verdict — no HBM round-trip between five XLA ops), the pure-jnp refs
   everywhere else.
-* ``ops.apply_programs`` — the fetch+VM+window half on its own, for the
-  sharded round (whose all_to_all exchange sits between dispatch and
-  apply, so the full fusion cannot cross it).
+* ``ops.apply_programs`` — the fetch+VM+window half on its own.  The
+  kernel takes one row space; the sharded round (whose all_to_all
+  exchange sits between dispatch and apply) has two — local tables,
+  global snapshot — and asks for the fused jnp reference by name.
 * ``ops.exchange_compact`` — the sharded exchange compaction (ranked
   single scatter into the per-destination buckets), kernelized.
 * ``ref.first_free_slots`` — the free-slot search both fused enqueue

@@ -18,11 +18,25 @@ bits) are gathered as split 16-bit halves — ``hi = x >> 16`` and
 (the ``stream_dispatch`` timestamp trick, generalized).  Exact at any
 bit pattern, sign of zero and NaN payloads included.
 
+The MXU passes ask for ``Precision.HIGHEST``: a default-precision f32
+matmul rounds its operands toward bf16 (8 mantissa bits), which does
+not carry a 16-bit half exactly — on a v5e chip the fused round lost
+sink records without it.
+
 VMEM sizing: the dominant intermediates are the (W, N') one-hot gather
 operands and the (W, R) register file, W = batch*max_out work lanes,
-N' = n_streams padded to 128, R = n_regs.  See docs/OPERATIONS.md for
-the queue/batch sizing notes; configs too large for VMEM should keep
-``fused_round`` off.
+N' = n_streams padded to 128, R = n_regs; narrow (N', 1..16) table
+columns also occupy whole 128-lane tiles.  At the 1,024-tenant IoT
+deployment (W = 512, N' = 3,712) the v5e compiler asks for 94.27 MiB of
+scoped VMEM for the fused kernel (93.47 MiB for the apply kernel)
+against Mosaic's default 16 MiB, so every kernel here raises the limit
+to ``_VMEM_LIMIT`` (v5e has 128 MiB per core).  That deployment then
+runs on the chip, with about 6 MiB to spare: a larger W * N' does not
+fit and should keep ``fused_round`` off.  The ``HIGHEST`` gathers take
+most of it: with default-precision dots the same kernels ask for
+21.39 / 21.52 MiB.  Code size and compile time grow with W * N' too
+(~30 MB of kernel code at that size; the fused superstep compiles in
+70-80 s on the chip).
 """
 from __future__ import annotations
 
@@ -31,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import program as pvm
 from repro.kernels.round_fuse.ref import (
@@ -38,6 +53,12 @@ from repro.kernels.round_fuse.ref import (
 from repro.kernels.sched_pop.ref import FAIR_SCALE, RANK_LIM
 
 _EPS = pvm._EPS
+
+# Scoped-VMEM ceiling for the kernels below.  Mosaic's default scope
+# (16 MiB on v5e) is too small for the one-hot gather operands at
+# deployment stream counts; v5e has 128 MiB of VMEM per core.
+_VMEM_LIMIT = 100 * 2 ** 20
+_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 # --------------------------------------------------------------------------
@@ -54,8 +75,10 @@ def _gather_i32(onehot: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """Exact int32 row gather as two 16-bit-half MXU matmuls.
     onehot: (W, n) f32; table: (n, X) int32 -> (W, X) int32."""
     hi = jnp.dot(onehot, (table >> 16).astype(jnp.float32),
+                 precision=jax.lax.Precision.HIGHEST,
                  preferred_element_type=jnp.float32)
     lo = jnp.dot(onehot, (table & 0xFFFF).astype(jnp.float32),
+                 precision=jax.lax.Precision.HIGHEST,
                  preferred_element_type=jnp.float32)
     return (hi.astype(jnp.int32) << 16) | lo.astype(jnp.int32)
 
@@ -255,9 +278,9 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
     row_bf = jax.lax.broadcasted_iota(jnp.int32, (batch, F), 0)
     row_wc = jax.lax.broadcasted_iota(jnp.int32, (W, C), 0)
     row_w1 = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+    lane_f = jax.lax.broadcasted_iota(jnp.int32, (1, F), 1)
     iota_col = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0)
     n_iota_col = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)
-    n_iota_row = jax.lax.broadcasted_iota(jnp.int32, (1, n_pad), 1)
     valid = valid_ref[:] != 0
     seq = seq_ref[:]
     tenant = tenant_ref[:]
@@ -266,7 +289,7 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
     ts = ts_ref[:]
     vals_bits = jax.lax.bitcast_convert_type(qvals_ref[:], jnp.int32)
     out_tbl = out_tbl_ref[:]
-    act_tbl = act_ref[:]
+    act_col = act_ref[:]
     key0 = jnp.where(valid, prio_ref[:], INT_MAX)
     tag0 = jnp.where(qlive_ref[:] != 0, 0, INT_MAX)
 
@@ -274,7 +297,7 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
     # subscriber-row / active-flag gathers, one winner per step ----------
     def step(b, carry):
         (k1, tag, taken, take, psid, pts, ppop, pact, pvals,
-         wi_t, wi_src, wi_ts, wi_vb) = carry
+         wi_t, wi_tcol, wi_src, wi_ts, wi_vb) = carry
         m1 = jnp.min(k1)
         c1 = k1 == m1
         m2 = jnp.min(jnp.where(c1, tag, INT_MAX))
@@ -286,16 +309,16 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
         was_valid = jnp.any(onehot & valid)
         t_i = jnp.sum(jnp.where(onehot, tenant, 0))
         w_i = jnp.sum(jnp.where(onehot, w, 0))
-        cnt = jnp.sum(jnp.where(taken & valid & (tenant == t_i), 1, 0)) \
-            + was_valid.astype(jnp.int32)
+        cnt = jnp.sum(jnp.where((taken != 0) & valid & (tenant == t_i),
+                                1, 0)) + was_valid.astype(jnp.int32)
         rank = jnp.minimum(cnt, RANK_LIM)
         tagval = jnp.where(w_i > 0,
                            rank * FAIR_SCALE // jnp.maximum(w_i, 1), 0)
-        bump = was_valid & (tenant == t_i) & valid & (w_i > 0) & ~taken
+        bump = was_valid & (tenant == t_i) & valid & (w_i > 0) & (taken == 0)
         tag = jnp.where(bump, tagval, tag)
         tag = jnp.where(onehot, INT_MAX, tag)
         k1 = jnp.where(onehot, INT_MAX, k1)
-        taken = taken | onehot
+        taken = jnp.where(onehot, 1, taken)
         # winner payload gathers (masked one-hot sums, exact in bits)
         sid_i = jnp.sum(jnp.where(onehot, sid, 0))
         ts_i = jnp.sum(jnp.where(onehot, ts, 0))
@@ -304,7 +327,7 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
         # stage-1 expansion for this winner: subscriber row + active
         row_i = jnp.clip(sid_i, 0, n_rows - 1)
         oh_n = n_iota_col == row_i
-        act_i = jnp.sum(jnp.where(n_iota_row == row_i, act_tbl, 0))
+        act_i = jnp.sum(jnp.where(oh_n, act_col, 0))
         trow = jnp.sum(jnp.where(oh_n, out_tbl, 0),
                        axis=0, keepdims=True)              # (1, F)
         e_valid = was_valid & (act_i != 0)
@@ -317,25 +340,31 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
         pact = jnp.where(col, (act_i != 0).astype(jnp.int32), pact)
         pvals = jnp.where(row_b == b, vals_i, pvals)
         wi_t = jnp.where(row_bf == b, trow, wi_t)
-        # work-item planes: rows b*F .. b*F+F-1 carry this winner
+        # work-item planes: rows b*F .. b*F+F-1 carry this winner (the
+        # target column is filled lane by lane: no (B, F) -> (W, 1) reshape)
+        for f in range(F):
+            t_f = jnp.sum(jnp.where(lane_f == f, trow, 0))
+            wi_tcol = jnp.where(row_w1 == b * F + f, t_f, wi_tcol)
         in_b = (row_w1 >= b * F) & (row_w1 < (b + 1) * F)
         wi_src = jnp.where(in_b, sid_i, wi_src)
         wi_ts = jnp.where(in_b, ts_i, wi_ts)
         in_bc = (row_wc >= b * F) & (row_wc < (b + 1) * F)
         wi_vb = jnp.where(in_bc, vals_i, wi_vb)
         return (k1, tag, taken, take, psid, pts, ppop, pact, pvals,
-                wi_t, wi_src, wi_ts, wi_vb)
+                wi_t, wi_tcol, wi_src, wi_ts, wi_vb)
 
     zero_b = jnp.zeros((1, batch), jnp.int32)
-    carry = (key0, tag0, jnp.zeros((1, Q), jnp.bool_),
+    carry = (key0, tag0, jnp.zeros((1, Q), jnp.int32),
              zero_b, zero_b, zero_b, zero_b, zero_b,
              jnp.zeros((batch, C), jnp.int32),
              jnp.zeros((batch, F), jnp.int32),
              jnp.zeros((W, 1), jnp.int32),
              jnp.zeros((W, 1), jnp.int32),
+             jnp.zeros((W, 1), jnp.int32),
              jnp.zeros((W, C), jnp.int32))
     (_, _, _, take, psid, pts, ppop, pact, pvals,
-     wi_t, wi_src, wi_ts, wi_vb) = jax.lax.fori_loop(0, batch, step, carry)
+     wi_t, wit_col, wi_src, wi_ts, wi_vb) = jax.lax.fori_loop(
+        0, batch, step, carry)
 
     take_ref[:] = take
     esid_ref[:] = psid
@@ -346,14 +375,11 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
     wit_ref[:] = wi_t
 
     # ---- stages 2 + 3 in the same kernel: winners never left VMEM ------
-    wit_col = jnp.reshape(wi_t, (W, 1))
     rows_col = jnp.clip(wit_col, 0, n_rows - 1)
     _pack_apply_outputs(
         _apply_body(layout, n_rows, prog_len,
                     in_tbl_ref[:], progs_ref[:], consts_ref[:],
-                    jnp.reshape(comp_ref[:], (n_pad, 1)),
-                    jnp.reshape(act_tbl, (n_pad, 1)),
-                    values_ref[:], jnp.reshape(tstamp_ref[:], (n_pad, 1)),
+                    comp_ref[:], act_col, values_ref[:], tstamp_ref[:],
                     rows_col, rows_col, wi_src,
                     jax.lax.bitcast_convert_type(wi_vb, jnp.float32),
                     wi_ts, wit_col >= 0),
@@ -389,9 +415,9 @@ def fused_round_call(prio_slot, seq, valid, t_slot, w_slot, sid, vals, ts,
         x = jnp.asarray(x, jnp.int32)
         return jnp.pad(x, (0, Qp - Q), constant_values=fill).reshape(1, Qp)
 
-    def nrow(x):
+    def ncol(x):
         return jnp.pad(jnp.asarray(x, jnp.int32),
-                       (0, Np - N)).reshape(1, Np)
+                       (0, Np - N)).reshape(Np, 1)
 
     def ntbl(x, dtype):
         x = jnp.asarray(x, dtype)
@@ -418,6 +444,7 @@ def fused_round_call(prio_slot, seq, valid, t_slot, w_slot, sid, vals, ts,
             jax.ShapeDtypeStruct((W, 1), i32b),           # passf
             jax.ShapeDtypeStruct((W, 1), i32b),           # badf
         ),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(qrow(prio_slot), qrow(seq), qrow(valid), qlive, qrow(t_slot),
       qrow(w_slot), qrow(sid), qrow(ts),
@@ -425,8 +452,8 @@ def fused_round_call(prio_slot, seq, valid, t_slot, w_slot, sid, vals, ts,
       ntbl(out_table, i32b), ntbl(in_table, i32b),
       ntbl(progs, i32b).reshape(Np, L * 4),
       ntbl(consts, jnp.float32),
-      nrow(is_composite), nrow(active),
-      ntbl(values, jnp.float32), nrow(timestamps))
+      ncol(is_composite), ncol(active),
+      ntbl(values, jnp.float32), ncol(timestamps))
     (take, psid, pts, ppop, pact, pvals, wi_t,
      new_vals, ts_out, live, keep, keep_ts, passf, badf) = outs
     flat = lambda x: x.reshape(-1)
@@ -448,13 +475,10 @@ def _apply_programs_kernel(wit_ref, tsid_ref, src_ref, wivals_ref, wits_ref,
                            nv_ref, tso_ref, live_ref, keep_ref, kts_ref,
                            pf_ref, bad_ref,
                            *, layout: RegLayout, n_rows: int, prog_len: int):
-    n_pad = in_tbl_ref.shape[0]
     _pack_apply_outputs(
         _apply_body(layout, n_rows, prog_len,
                     in_tbl_ref[:], progs_ref[:], consts_ref[:],
-                    jnp.reshape(comp_ref[:], (n_pad, 1)),
-                    jnp.reshape(act_ref[:], (n_pad, 1)),
-                    values_ref[:], jnp.reshape(tstamp_ref[:], (n_pad, 1)),
+                    comp_ref[:], act_ref[:], values_ref[:], tstamp_ref[:],
                     wit_ref[:], tsid_ref[:], src_ref[:], wivals_ref[:],
                     wits_ref[:], wivalid_ref[:] != 0),
         (nv_ref, tso_ref, live_ref, keep_ref, kts_ref, pf_ref, bad_ref))
@@ -497,6 +521,7 @@ def apply_programs_call(layout: RegLayout, in_table, progs, consts,
             jax.ShapeDtypeStruct((W, 1), i32b),           # passf
             jax.ShapeDtypeStruct((W, 1), i32b),           # badf
         ),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(wcol(rows), wcol(t_sid), wcol(wi_src),
       jnp.asarray(wi_vals, jnp.float32), wcol(wi_ts), wcol(wi_valid),
@@ -526,7 +551,15 @@ def _exchange_compact_kernel(wit_ref, src_ref, wits_ref, wiits_ref,
     routed = dest < n_shards
     d_iota = jax.lax.broadcasted_iota(jnp.int32, (n_shards, W), 0)
     onehot = routed & (d_iota == dest)                     # (D, W)
-    cum = jnp.cumsum(onehot.astype(jnp.int32), axis=1) - 1
+    # inclusive running count along the lanes as a matmul with the
+    # upper-triangular ones matrix (Mosaic has no cumsum); 0/1 operands
+    # and counts <= W are exact in f32
+    w_row = jax.lax.broadcasted_iota(jnp.int32, (W, W), 0)
+    w_col = jax.lax.broadcasted_iota(jnp.int32, (W, W), 1)
+    cum = jnp.dot(onehot.astype(jnp.float32),
+                  (w_row <= w_col).astype(jnp.float32),
+                  precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32).astype(jnp.int32) - 1
     rank = jnp.sum(jnp.where(onehot, cum, 0), axis=0, keepdims=True)
     fits = routed & (rank < slots)
     slot = jnp.where(fits, dest * slots + rank, DE)        # (1, W)
@@ -570,6 +603,7 @@ def exchange_compact_call(wi_t, wi_src, wi_ts, wi_its, wi_vals, dest_shard,
             jax.ShapeDtypeStruct((DE, C), jnp.float32),
             jax.ShapeDtypeStruct((1, Wp), jnp.int32),
         ),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(wrow(wi_t), wrow(wi_src), wrow(wi_ts), wrow(wi_its),
       jnp.pad(jnp.asarray(wi_vals, jnp.float32), ((0, Wp - W), (0, 0))),

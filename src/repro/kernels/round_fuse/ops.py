@@ -75,11 +75,10 @@ def apply_programs(layout: RegLayout, in_table, progs, consts, is_composite,
     the reduced-branch VM, returning the raw masks ``(new_vals, ts_out,
     live, keep, keep_ts, passf, badf)``.  The kernel path requires the
     tables and the value/timestamp snapshot to share one row space
-    (``rows is t_sid`` up to clipping), which the sharded round only
-    satisfies for the global snapshot — otherwise pass
-    ``use_kernel=False``."""
+    (``rows is t_sid`` up to clipping) and asserts it; a caller whose row
+    spaces differ asks for the reference with ``use_kernel=False``."""
     use_kernel, interp = _pick(use_kernel, interpret)
-    if use_kernel and in_table.shape[0] == timestamps_by_sid.shape[0]:
+    if use_kernel:
         from repro.kernels.round_fuse.kernel import apply_programs_call
         return apply_programs_call(layout, in_table, progs, consts,
                                    is_composite, active, rows, t_sid, wi_src,

@@ -65,16 +65,16 @@ def _sched_pop_kernel(prio_ref, seq_ref, valid_ref, live_ref, tenant_ref,
         was_valid = jnp.any(onehot & valid)
         t_i = jnp.sum(jnp.where(onehot, tenant, 0))
         w_i = jnp.sum(jnp.where(onehot, w, 0))
-        cnt = jnp.sum(jnp.where(taken & valid & (tenant == t_i), 1, 0)) \
-            + was_valid.astype(jnp.int32)
+        cnt = jnp.sum(jnp.where((taken != 0) & valid & (tenant == t_i),
+                                1, 0)) + was_valid.astype(jnp.int32)
         rank = jnp.minimum(cnt, RANK_LIM)
         tagval = jnp.where(w_i > 0,
                            rank * FAIR_SCALE // jnp.maximum(w_i, 1), 0)
-        bump = was_valid & (tenant == t_i) & valid & (w_i > 0) & ~taken
+        bump = was_valid & (tenant == t_i) & valid & (w_i > 0) & (taken == 0)
         tag = jnp.where(bump, tagval, tag)
         tag = jnp.where(onehot, INT_MAX, tag)
         k1 = jnp.where(onehot, INT_MAX, k1)
-        taken = taken | onehot
+        taken = jnp.where(onehot, 1, taken)
         # fused winner gather: masked one-hot sums over int32 (exact at
         # any bit pattern; payload floats ride as their bits)
         col = iota_b == b
@@ -90,7 +90,7 @@ def _sched_pop_kernel(prio_ref, seq_ref, valid_ref, live_ref, tenant_ref,
     zero_b = jnp.zeros((1, batch), jnp.int32)
     _, _, _, take, psid, pts, pvalid, pvals = jax.lax.fori_loop(
         0, batch, step,
-        (key0, tag0, jnp.zeros((1, Q), jnp.bool_),
+        (key0, tag0, jnp.zeros((1, Q), jnp.int32),
          zero_b, zero_b, zero_b, zero_b,
          jnp.zeros((batch, C), jnp.int32)))
     take_ref[:] = take

@@ -73,9 +73,12 @@ def build_suite(n_tenants: int = 12, *,
     target; ``fused_round`` pins the engine's fused/staged round path
     (None = config default) for the differential harness."""
     kinds = [kinds[i % len(kinds)] for i in range(n_tenants)]
-    n_streams = sum(_SIDS_PER_KIND[k] for k in kinds) + 2
-    n_streams = -(-n_streams // n_shards) * n_shards   # pad to shard multiple
     over = dict(cfg_overrides or {})
+    # an ``n_streams`` override can only add rows (e.g. a 1-device twin of
+    # a sharded suite, padded alike)
+    n_streams = max(sum(_SIDS_PER_KIND[k] for k in kinds) + 2,
+                    over.pop("n_streams", 0))
+    n_streams = -(-n_streams // n_shards) * n_shards   # pad to shard multiple
     if fused_round is not None:
         over["fused_round"] = fused_round
     over.setdefault("superstep", 4)

@@ -15,6 +15,7 @@ from jax import monitoring
 
 from repro.core import EngineConfig, Registry, StreamEngine, create_engine
 from repro.core.engine import INT_MIN
+from repro.launch.compiles import compile_count
 
 N_DEV = len(jax.devices())
 
@@ -104,6 +105,7 @@ def test_live_churn_zero_retrace_bit_identical(n_shards):
     seed0 = regA.create_stream(tA, "s0", ["v"])
     seed1 = regA.create_stream(tA, "s1", ["v"])
     engA = create_engine(regA)
+    step0 = compile_count(engA._step)
     engA.drain(max_rounds=2)           # trace the round before any churn
 
     # warm every admission op once (their own one-time compiles), then
@@ -113,7 +115,7 @@ def test_live_churn_zero_retrace_bit_identical(n_shards):
     engA.revoke_subscription(warm, seed1)
     engA.swap_program(warm, {"v": "in0.v + 1"})
     engA.revoke_stream(warm)
-    cache0 = engA._step._cache_size()
+    cache0 = compile_count(engA._step) - step0
     jax.block_until_ready(engA.tables.active)
     n_traces = len(_TRACES)
 
@@ -128,7 +130,7 @@ def test_live_churn_zero_retrace_bit_identical(n_shards):
     _run(engA, _schedule(srcsA, waves=2))
     jax.block_until_ready(engA.state.timestamps)
 
-    assert engA._step._cache_size() == cache0 == 1
+    assert compile_count(engA._step) - step0 == cache0 == 1
     assert len(_TRACES) == n_traces, \
         f"churn recompiled: {_TRACES[n_traces:]}"
 
@@ -342,7 +344,7 @@ def test_sharded_rebalance_migrates_state():
     eng.post(a, [10.0], ts=1)
     eng.drain()
     assert eng._occupancy[0] - eng._occupancy[1] >= 4
-    cache0 = eng._step._cache_size()
+    cache0 = compile_count(eng._step)
 
     moved = eng.rebalance()
     assert moved >= 2
@@ -353,7 +355,7 @@ def test_sharded_rebalance_migrates_state():
     eng.post(a, [20.0], ts=2)
     eng.drain()
     assert [float(eng.value_of(c)[0]) for c in comps] == [20, 21, 22, 23]
-    assert eng._step._cache_size() == cache0
+    assert compile_count(eng._step) == cache0
 
     eng.post(a, [1.0], ts=3)                  # in-flight SUs block moves
     with pytest.raises(ValueError, match="flight|drain"):
@@ -462,6 +464,7 @@ def test_bridge_admit_route_mid_flight():
     t = reg.create_tenant("t")
     a = reg.create_stream(t, "a", ["v"])
     eng = create_engine(reg)
+    step0 = compile_count(eng._step)
     eng.post(a, [1.0], ts=1)
     eng.drain()                               # engine already running
 
@@ -472,7 +475,7 @@ def test_bridge_admit_route_mid_flight():
     assert out is not None
     model, resp = out
     assert model.model_backed and model.sid in mbs.routes
-    assert eng._step._cache_size() == 1       # no retrace from serving path
+    assert compile_count(eng._step) - step0 == 1  # no retrace from serving
 
     mbs.revoke_route(model)
     assert model.sid not in mbs.routes

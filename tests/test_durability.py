@@ -10,6 +10,7 @@ import pytest
 
 import jax
 from jax import monitoring
+from repro.launch.compiles import compile_count
 
 from repro.checkpoint.ckpt import CheckpointManager, latest_step
 from repro.core import (EngineConfig, Registry, create_engine,
@@ -190,7 +191,7 @@ def test_durability_ops_zero_retrace(n_shards):
     eng.dead_letters()
     eng.drain()
 
-    cache0 = eng._step._cache_size()
+    cache0 = compile_count(eng._step)
     jax.block_until_ready(eng.state.timestamps)
     n_traces = len(_TRACES)
     for w in range(3):                       # steady-state churn
@@ -205,7 +206,7 @@ def test_durability_ops_zero_retrace(n_shards):
         eng.revoke_stream(late2)
         eng.dead_letters()
     jax.block_until_ready(eng.state.timestamps)
-    assert eng._step._cache_size() == cache0
+    assert compile_count(eng._step) == cache0
     assert len(_TRACES) == n_traces
 
 

@@ -12,19 +12,13 @@ import numpy as np
 import pytest
 
 import jax
-from jax import monitoring
 
 from repro.core import (EngineConfig, Registry, create_engine,
                         restore_engine)
+from repro.launch.compiles import compile_count
 
 N_DEV = len(jax.devices())
 
-# one "/jax/core/compile/backend_compile_duration" event fires per compiled
-# program; counting those (and nothing else) counts retraces exactly
-_COMPILES = []
-monitoring.register_event_duration_secs_listener(
-    lambda name, dur, **kw: _COMPILES.append(name)
-    if name == "/jax/core/compile/backend_compile_duration" else None)
 
 
 def _require(n_shards):
@@ -216,15 +210,15 @@ def test_resize_exactly_one_retrace():
         ts, w, deltas = 1, 0, []
         _run(eng, srcs, range(w, w + 2), ts, K)
         for n_to in schedule:
-            before = len(_COMPILES)
+            before = compile_count()
             eng.resize(n_to)
             _run(eng, srcs, [w + 2], ts + 8 * w, K)   # first post-resize step
             jax.block_until_ready(eng.state.timestamps)
-            resize_cost = len(_COMPILES) - before
-            before = len(_COMPILES)
+            resize_cost = compile_count() - before
+            before = compile_count()
             _run(eng, srcs, [w + 3], ts + 8 * w + 4, K)  # steady state
             jax.block_until_ready(eng.state.timestamps)
-            deltas.append((resize_cost, len(_COMPILES) - before))
+            deltas.append((resize_cost, compile_count() - before))
             w += 4
         return deltas
 

@@ -17,17 +17,13 @@ import numpy as np
 import pytest
 
 import jax
-from jax import monitoring
 
 from repro.core import (EngineConfig, Registry, create_engine,
                         restore_engine)
+from repro.launch.compiles import compile_count
 
 N_DEV = len(jax.devices())
 
-_COMPILES = []
-monitoring.register_event_duration_secs_listener(
-    lambda name, dur, **kw: _COMPILES.append(name)
-    if name == "/jax/core/compile/backend_compile_duration" else None)
 
 SHARD_LEVELS = (1, 2, 4)
 K = 2
@@ -134,7 +130,7 @@ def test_chaos_soak(tmp_path):
     resizes = 0
     for step in range(200):
         resized = rng.rand() < 0.08
-        before = len(_COMPILES)
+        before = compile_count()
         if resized:
             n_now = eng.cfg.n_shards
             choices = [n for n in SHARD_LEVELS if n != n_now]
@@ -143,7 +139,7 @@ def test_chaos_soak(tmp_path):
         ts = _churn(eng, tens, srcs, rng, ts, admitted)
         eng.superstep(K)
         jax.block_until_ready(eng.state.timestamps)
-        compiled = len(_COMPILES) - before
+        compiled = compile_count() - before
         if resized:
             assert compiled <= 1, \
                 f"step {step}: resize cost {compiled} compiles (max 1)"

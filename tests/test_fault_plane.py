@@ -28,6 +28,7 @@ import jax
 from repro.core import EngineConfig, Registry, create_engine, restore_engine
 from repro.checkpoint import ckpt
 from repro.launch import chaos as C
+from repro.launch.compiles import compile_count
 from repro.launch.supervise import Supervisor, supervised_run
 
 N_DEV = len(jax.devices())
@@ -72,9 +73,10 @@ class _RefBreaker:
     """Host model of one row's breaker window machine (mirrors
     ``fault_events``/``fault_phase``): a fault at round ``rid`` restarts
     the window when it fell outside ``W`` rounds of the window's epoch (or
-    the window is empty), trips at ``count >= F`` while not yet
-    quarantined, and ``unquarantine`` clears the window but not the
-    lifetime total."""
+    the window is empty), a fault-free round past expiry decays the count
+    to 0, it trips at ``count >= F`` while not yet quarantined, and
+    ``unquarantine`` clears the window but not the lifetime total.  The
+    clock is the engine's round counter (host edits run no round)."""
 
     def __init__(self, W, F):
         self.W, self.F = W, F
@@ -83,9 +85,13 @@ class _RefBreaker:
         self.total = 0
         self.quar = False
 
-    def fault(self, rid):
-        self.total += 1
+    def round(self, rid, fault):
         in_win = (rid - self.epoch) < self.W
+        if not fault:
+            if not in_win:
+                self.count = 0
+            return
+        self.total += 1
         if not in_win or self.count == 0:
             self.epoch, self.count = rid, 1
         else:
@@ -104,7 +110,8 @@ def _check_breaker_sequence(ops, W=4, F=2, cross_shard=False):
     ref = _RefBreaker(W, F)
     row = comp.sid
     ts = 1
-    for rid, op in enumerate(ops):
+    rid = 0
+    for op in ops:
         if op == "unq":
             eng.unquarantine(comp)
             ref.unquarantine()
@@ -115,8 +122,8 @@ def _check_breaker_sequence(ops, W=4, F=2, cross_shard=False):
             eng.post(src, [1.0], ts=ts)
         ts += 1
         eng.round()
-        if op == "poison":
-            ref.fault(rid)
+        ref.round(rid, op == "poison")
+        rid += 1
     fc = eng.fault_counters()
     assert bool(fc["quarantined"][row]) == ref.quar, ops
     assert int(fc["fault_total"][row]) == ref.total, ops
@@ -255,8 +262,11 @@ def test_fused_staged_poison_differential(n_shards, K):
     e0, st0 = _diff_build(False, n_shards, K)
     e1, st1 = _diff_build(True, n_shards, K)
     assert e0._path == "staged" and e1._path == "fused"
-    _diff_drive(e0, st0, K)
-    _diff_drive(e1, st1, K)
+    scans = []                               # each engine's scan compiles
+    for eng, st in ((e0, st0), (e1, st1)):
+        scan0 = compile_count(eng._superstep_fn(K))
+        _diff_drive(eng, st, K)
+        scans.append(compile_count(eng._superstep_fns[K]) - scan0)
     a, b = _state_arrays(e0), _state_arrays(e1)
     assert a.keys() == b.keys()
     for k in a:
@@ -265,8 +275,8 @@ def test_fused_staged_poison_differential(n_shards, K):
         np.testing.assert_array_equal(
             x.view(np.int32) if x.dtype == np.float32 else x,
             y.view(np.int32) if y.dtype == np.float32 else y, err_msg=k)
-    for eng in (e0, e1):                     # the zero-retrace contract
-        assert eng._superstep_fns[K]._cache_size() == 1
+    assert scans == [1, 1]                   # the zero-retrace contract
+    for eng in (e0, e1):
         fc = eng.fault_counters()
         assert int(fc["fault_total"][st0[2].sid]) > 0   # c0 really faulted
         assert eng.counters()["nonfinite"] > 0
@@ -553,12 +563,15 @@ def test_chaos_soak_200_supersteps(tmp_path):
             raise C.ShardKill(f"soak kill @{step}")
 
     eng, comp, feed = rig(tmp_path / "a", 8)
+    scan0 = compile_count(eng._superstep_fn(1))
     report = supervised_run(eng, str(tmp_path / "a"), n_steps,
                             feed=feed, chaos=chaos, K=1,
                             escalate_after=10**9)
     assert report.recovered and len(report.incidents) == 2
     assert report.engine._steps_done == n_steps
-    assert report.engine._superstep_fns[1]._cache_size() == 1  # no retrace
+    # no retrace: the first engine and each restored one compile once
+    assert compile_count(report.engine._superstep_fns[1]) - scan0 \
+        == 1 + len(report.incidents)
     fc = report.engine.fault_counters()
     assert int(fc["fault_total"].sum()) == len(poison)
     assert bool(fc["quarantined"][comp.sid])          # breaker did trip

@@ -41,6 +41,7 @@ except ImportError:
 
 from repro.core import EngineConfig, Registry, create_engine
 from repro.core.slo import SLOTracker, weights_from_slo
+from repro.launch.compiles import compile_count
 from repro.workloads import TraceConfig, build_suite
 from repro.workloads.runner import sink_records
 
@@ -325,14 +326,14 @@ def test_qos_weights_improve_light_p99():
     assert slo_on.pressure()[light.tid] < slo_off.pressure()[light_off.tid]
 
     # zero-retrace churn: close the SLO -> weights loop live, every round
-    cache0 = eng._step._cache_size()
+    cache0 = compile_count(eng._step)
     for r in range(6):
         slo_on.set_slo(light, 2 + r % 2)
         w = weights_from_slo(slo_on, base=1, boost=8)
         for tid in (heavy.tid, light.tid):
             eng.set_weight(tid, int(w[tid]))
         slo_on.observe(eng.latency_records(eng.round()))
-    assert eng._step._cache_size() - cache0 == 0
+    assert compile_count(eng._step) - cache0 == 0
 
 
 # --------------------------------------------------------------------------

@@ -34,6 +34,7 @@ from jax import monitoring
 
 from repro.core import EngineConfig, Registry, create_engine, init_state
 from repro.core.engine import FAIR_SCALE, _enqueue, _pop
+from repro.launch.compiles import compile_count
 
 N_DEV = len(jax.devices())
 
@@ -449,6 +450,8 @@ def test_qos_edits_zero_retrace(n_shards):
              for i, s in enumerate(srcs)]
     eng = create_engine(reg)
     K = 3
+    step0 = compile_count(eng._step)
+    scan0 = compile_count(eng._superstep_fn(K))
 
     # warm: the round, the superstep scan, and both QoS ops
     eng.post(srcs[0], [1.0], 1)
@@ -457,8 +460,8 @@ def test_qos_edits_zero_retrace(n_shards):
     eng.set_weight(t0, 1)
     eng.set_quota(t0, 1, 1)
     jax.block_until_ready(eng.state.timestamps)
-    cache_step = eng._step._cache_size()
-    cache_scan = eng._superstep_fns[K]._cache_size()
+    cache_step = compile_count(eng._step) - step0
+    cache_scan = compile_count(eng._superstep_fns[K]) - scan0
     n_traces = len(_TRACES)
 
     ts = 10
@@ -473,8 +476,8 @@ def test_qos_edits_zero_retrace(n_shards):
         ts += K + 1
     jax.block_until_ready(eng.state.timestamps)
 
-    assert eng._step._cache_size() == cache_step == 1
-    assert eng._superstep_fns[K]._cache_size() == cache_scan == 1
+    assert compile_count(eng._step) - step0 == cache_step == 1
+    assert compile_count(eng._superstep_fns[K]) - scan0 == cache_scan == 1
     assert len(_TRACES) == n_traces, \
         f"QoS knob edits recompiled: {_TRACES[n_traces:]}"
     # and the knobs actually took: t0 is shaped, t1 unlimited

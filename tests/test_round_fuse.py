@@ -32,6 +32,7 @@ except ImportError:
 import jax.numpy as jnp
 
 from repro.core import EngineConfig, Registry, create_engine
+from repro.launch.compiles import compile_count
 
 
 # --------------------------------------------------------------------------
@@ -122,11 +123,12 @@ def test_fused_bit_identical_to_staged(n_shards, superstep):
 def test_fused_zero_retrace_under_churn():
     """The retrace contract holds on the fused path: weight/quota edits,
     admission, revocation and program swaps (to fusable programs) are all
-    table edits — the compiled step's trace-cache stays at one entry."""
+    table edits — the compiled step compiles exactly once."""
     eng, (t0, t1), srcs, c0 = _build(True)
     assert eng._path == "fused"
+    step0 = compile_count(eng._step)
     _run(eng, srcs, 2, seed=1)
-    cache0 = eng._step._cache_size()
+    cache0 = compile_count(eng._step) - step0
     assert cache0 == 1
 
     eng.set_weight(t0, 5)
@@ -144,7 +146,7 @@ def test_fused_zero_retrace_under_churn():
     _run(eng, srcs + [s_new], 2, seed=4)
 
     assert eng._path == "fused"
-    assert eng._step._cache_size() == cache0 == 1
+    assert compile_count(eng._step) - step0 == cache0 == 1
 
 
 def test_fallback_flips_on_transcendental_swap():

@@ -31,6 +31,7 @@ from jax import monitoring
 
 from repro.core import EngineConfig, Registry, create_engine, init_state
 from repro.core.engine import FAIR_SCALE, RANK_LIM, _enqueue, _pop
+from repro.launch.compiles import compile_count
 from repro.kernels.sched_pop.ops import sched_pop
 from repro.kernels.sched_pop import ref as sched_ref
 
@@ -273,12 +274,14 @@ def test_packed_sched_zero_retrace_across_knob_churn(n_shards):
     _require(n_shards)
     eng, heavy, light, srcs = _build_engine("packed", n_shards)
     K = 2
+    step0 = compile_count(eng._step)
+    scan0 = compile_count(eng._superstep_fn(K))
     eng.post(srcs[0], [1.0], 1)
     eng.round()
     eng.superstep(K)
     jax.block_until_ready(eng.state.timestamps)
-    cache_step = eng._step._cache_size()
-    cache_scan = eng._superstep_fns[K]._cache_size()
+    cache_step = compile_count(eng._step) - step0
+    cache_scan = compile_count(eng._superstep_fns[K]) - scan0
     n_traces = len(_TRACES)
     ts = 10
     for r in range(5):
@@ -290,7 +293,7 @@ def test_packed_sched_zero_retrace_across_knob_churn(n_shards):
         eng.round() if r % 2 else eng.superstep(K)
         ts += K + 1
     jax.block_until_ready(eng.state.timestamps)
-    assert eng._step._cache_size() == cache_step == 1
-    assert eng._superstep_fns[K]._cache_size() == cache_scan == 1
+    assert compile_count(eng._step) - step0 == cache_step == 1
+    assert compile_count(eng._superstep_fns[K]) - scan0 == cache_scan == 1
     assert len(_TRACES) == n_traces, \
         f"packed-scheduler knob churn recompiled: {_TRACES[n_traces:]}"

@@ -103,16 +103,12 @@ def test_compression_error_feedback():
 
 
 def test_compressed_psum_shard_map():
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:                       # older jax
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compression import compressed_psum
     mesh = jax.make_mesh((1,), ("pod",))
     x = jnp.arange(8, dtype=jnp.float32)
-    g = shard_map(lambda v: compressed_psum(v, "pod"), mesh=mesh,
-                  in_specs=P(), out_specs=P())
+    g = jax.shard_map(lambda v: compressed_psum(v, "pod"), mesh=mesh,
+                      in_specs=P(), out_specs=P())
     got = g(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(x), atol=0.05)
 

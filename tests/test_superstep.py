@@ -13,6 +13,7 @@ from jax import monitoring
 
 from repro.core import EngineConfig, Registry, create_engine
 from repro.core.engine import StreamEngine
+from repro.launch.compiles import compile_count
 
 N_DEV = len(jax.devices())
 
@@ -131,6 +132,7 @@ def test_superstep_churn_at_boundaries_bit_identical(n_shards):
     _, srcsB, compsB, engB = _build(cfg)
 
     # trace the scan + warm every admission op before counting
+    scan0 = compile_count(engB._superstep_fn(K))
     for eng, srcs in ((engA, srcsA), (engB, srcsB)):
         eng.post(srcs[0], [1.0], 1)
     _ = [engA.round() for _ in range(K)]
@@ -140,7 +142,7 @@ def test_superstep_churn_at_boundaries_bit_identical(n_shards):
         warm = eng.admit_composite(t, "warm", ["v"], [srcs[0]],
                                    {"v": "in0.v"})
         eng.revoke_stream(warm)
-    cacheA = engB._superstep_fns[K]._cache_size()
+    cacheA = compile_count(engB._superstep_fns[K]) - scan0
     jax.block_until_ready(engB.tables.active)
     n_traces = len(_TRACES)
 
@@ -162,7 +164,7 @@ def test_superstep_churn_at_boundaries_bit_identical(n_shards):
         engB.superstep(K)
 
     jax.block_until_ready(engB.state.timestamps)
-    assert engB._superstep_fns[K]._cache_size() == cacheA == 1
+    assert compile_count(engB._superstep_fns[K]) - scan0 == cacheA == 1
     assert len(_TRACES) == n_traces, \
         f"superstep churn recompiled: {_TRACES[n_traces:]}"
     _assert_engines_equal(engA, engB)
@@ -176,6 +178,7 @@ def test_superstep_zero_retrace_across_queue_depth():
     cfg = _cfg()
     _, srcs, _, eng = _build(cfg)
     K = 4
+    scan0 = compile_count(eng._superstep_fn(K))
     eng.post(srcs[0], [1.0], 1)
     eng.superstep(K)                      # first trace
     jax.block_until_ready(eng.state.timestamps)
@@ -188,7 +191,7 @@ def test_superstep_zero_retrace_across_queue_depth():
         eng.superstep(K)
         ts += 5
     jax.block_until_ready(eng.state.timestamps)
-    assert eng._superstep_fns[K]._cache_size() == 1
+    assert compile_count(eng._superstep_fns[K]) - scan0 == 1
     assert len(_TRACES) == n_traces, \
         f"queue depth retraced: {_TRACES[n_traces:]}"
 
