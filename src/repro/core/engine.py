@@ -189,6 +189,10 @@ STAT_KEYS = (
     # (breaker-tripped or host `quarantine()`), and dead letters whose
     # redelivery was refused because the stream is revoked/quarantined
     "dropped_poisoned", "redeliver_rejected",
+    # stage-4 winners beyond a round's `sink_buffer`: stored (and
+    # re-enqueued when subscribed) but absent from the external sink — no
+    # dead letter, so no `dropped_` prefix
+    "sink_overflow",
 )
 
 # Dead-letter drop classes: every ``dropped_*`` stat has a DLQ reason code,
@@ -592,8 +596,10 @@ def store_and_emit(cfg: EngineConfig, tables: DeviceTables,
     stats["enqueued"] += fanout_more.sum(dtype=jnp.int32)
     stats["queued_in"] += fanout_more.sum(dtype=jnp.int32) - dropped
 
-    # external sink buffer: first `sink_buffer` winners this round
+    # external sink buffer: first `sink_buffer` winners this round; the
+    # rest are counted, not delivered
     sink_rank = jnp.cumsum(win.astype(jnp.int32)) - 1
+    stats["sink_overflow"] += (win & (sink_rank >= S)).sum(dtype=jnp.int32)
     sdest = jnp.where(win & (sink_rank < S), sink_rank, S)
     sink = SinkBatch(
         sid=jnp.zeros((S,), jnp.int32).at[sdest].set(emit_sid, mode="drop"),
@@ -841,79 +847,85 @@ def make_step(
             stats = dict(state.stats)
 
             # ---- phase 0: ingest external SUs ---------------------------
-            i_sid = jnp.clip(ingest.sid, 0, N - 1)
-            state, stats = ingest_phase(state, stats, ingest, i_sid, i_sid,
-                                        tables.active[i_sid], N,
-                                        tables.tenant[i_sid],
-                                        tables.quota, tables.burst,
-                                        fast_free=True,
-                                        quarantined=state.quarantined[i_sid])
+            with jax.named_scope("ingest"):
+                i_sid = jnp.clip(ingest.sid, 0, N - 1)
+                state, stats = ingest_phase(
+                    state, stats, ingest, i_sid, i_sid,
+                    tables.active[i_sid], N, tables.tenant[i_sid],
+                    tables.quota, tables.burst, fast_free=True,
+                    quarantined=state.quarantined[i_sid])
 
             # ---- stages 1-3 fused: pop, fan-out, fetch+VM, window gate --
             # quarantined rows ride the kernel's existing active gate (no
             # signature change): the *effective* mask keeps them from
             # dispatching or winning; the real mask is re-read outside so
             # revoked and poisoned drops stay separately accounted
-            eff_active = tables.active & ~state.quarantined
-            prio_slot = tables.priority[state.q_sid]
-            t_slot = jnp.clip(tables.tenant[state.q_sid], 0, T - 1)
-            w_slot = tables.weight[t_slot]
-            take, (e_sid, e_vals, e_ts, e_pop, e_act), wi_t, applied = \
-                fused_stages(prio_slot, state.q_seq, state.q_valid, t_slot,
-                             w_slot, state.q_sid, state.q_vals, state.q_ts,
-                             B, tables.out_table, tables.in_table,
-                             tables.progs, tables.consts,
-                             tables.is_composite, eff_active,
-                             state.values, state.timestamps, layout)
-            # the ingest stamps of the popped slots ride outside the kernel:
-            # `take` is the same slot selection the staged _pop returns, so
-            # this gather keeps the two paths bit-identical
-            e_its = state.q_its[take]
-            state = state._replace(
-                q_valid=state.q_valid.at[take].set(False))
-            stats["popped"] += e_pop.sum(dtype=jnp.int32)
-            # events whose stream was revoked/quarantined while queued drop
-            # here (split so triage can tell a torn-down tenant from a
-            # breaker-tripped one)
-            e_row = jnp.clip(e_sid, 0, N - 1)
-            e_real = tables.active[e_row]
-            e_poison = e_pop & e_real & state.quarantined[e_row]
-            stats["dropped_revoked"] += (e_pop & ~e_real).sum(dtype=jnp.int32)
-            state = dlq_append(state, e_sid, e_vals, e_ts,
-                               tables.tenant[e_row],
-                               DLQ_REVOKED, e_pop & ~e_real, its=e_its)
-            stats["dropped_poisoned"] += e_poison.sum(dtype=jnp.int32)
-            state = dlq_append(state, e_sid, e_vals, e_ts,
-                               tables.tenant[e_row],
-                               DLQ_POISONED, e_poison, its=e_its)
-            new_vals, ts_out, live, keep, keep_ts, passf, badf = applied
-            stats["processed"] += live.sum(dtype=jnp.int32)
-            stats["discarded_stale"] += (live & ~keep_ts).sum(dtype=jnp.int32)
-            stats["filtered"] += \
-                (live & keep_ts & ~passf).sum(dtype=jnp.int32)
-            stats["nonfinite"] += (badf & (wi_t >= 0)).sum(dtype=jnp.int32)
+            with jax.named_scope("round_fuse"):
+                eff_active = tables.active & ~state.quarantined
+                prio_slot = tables.priority[state.q_sid]
+                t_slot = jnp.clip(tables.tenant[state.q_sid], 0, T - 1)
+                w_slot = tables.weight[t_slot]
+                take, (e_sid, e_vals, e_ts, e_pop, e_act), wi_t, applied = \
+                    fused_stages(prio_slot, state.q_seq, state.q_valid,
+                                 t_slot, w_slot, state.q_sid, state.q_vals,
+                                 state.q_ts, B, tables.out_table,
+                                 tables.in_table, tables.progs,
+                                 tables.consts, tables.is_composite,
+                                 eff_active, state.values,
+                                 state.timestamps, layout)
+            with jax.named_scope("pop_accounting"):
+                # the ingest stamps of the popped slots ride outside the
+                # kernel: `take` is the same slot selection the staged
+                # _pop returns, so this gather keeps the two paths
+                # bit-identical
+                e_its = state.q_its[take]
+                state = state._replace(
+                    q_valid=state.q_valid.at[take].set(False))
+                stats["popped"] += e_pop.sum(dtype=jnp.int32)
+                # events whose stream was revoked/quarantined while queued
+                # drop here (split so triage can tell a torn-down tenant
+                # from a breaker-tripped one)
+                e_row = jnp.clip(e_sid, 0, N - 1)
+                e_real = tables.active[e_row]
+                e_poison = e_pop & e_real & state.quarantined[e_row]
+                stats["dropped_revoked"] += \
+                    (e_pop & ~e_real).sum(dtype=jnp.int32)
+                state = dlq_append(state, e_sid, e_vals, e_ts,
+                                   tables.tenant[e_row],
+                                   DLQ_REVOKED, e_pop & ~e_real, its=e_its)
+                stats["dropped_poisoned"] += e_poison.sum(dtype=jnp.int32)
+                state = dlq_append(state, e_sid, e_vals, e_ts,
+                                   tables.tenant[e_row],
+                                   DLQ_POISONED, e_poison, its=e_its)
+                new_vals, ts_out, live, keep, keep_ts, passf, badf = applied
+                stats["processed"] += live.sum(dtype=jnp.int32)
+                stats["discarded_stale"] += \
+                    (live & ~keep_ts).sum(dtype=jnp.int32)
+                stats["filtered"] += \
+                    (live & keep_ts & ~passf).sum(dtype=jnp.int32)
+                stats["nonfinite"] += (badf & (wi_t >= 0)).sum(dtype=jnp.int32)
 
             # ---- stage 4: store, trigger actions and emit ---------------
-            t = jnp.clip(wi_t, 0, N - 1)
-            wi_src = jnp.repeat(e_sid, F)
-            wi_its = jnp.repeat(e_its, F)
-            state, stats, sink = store_and_emit(cfg, tables, state, stats,
-                                                t, t, wi_src, new_vals,
-                                                ts_out, keep, N,
-                                                fast_free=True,
-                                                wi_its=wi_its)
+            with jax.named_scope("store_emit"):
+                t = jnp.clip(wi_t, 0, N - 1)
+                wi_src = jnp.repeat(e_sid, F)
+                wi_its = jnp.repeat(e_its, F)
+                state, stats, sink = store_and_emit(
+                    cfg, tables, state, stats, t, t, wi_src, new_vals,
+                    ts_out, keep, N, fast_free=True, wi_its=wi_its)
 
             # ---- fault plane: breaker window + device auto-quarantine ---
-            fan = (wi_t.reshape(B, F) >= 0).sum(axis=1, dtype=jnp.int32)
-            fault_evt = fault_events(tables.breaker, badf, wi_t >= 0, t,
-                                     fan, e_pop & e_act, e_row, N)
-            state, stats = fault_phase(
-                state, stats, tables.breaker, fault_evt, tables.active,
-                tables.tenant, jnp.clip(state.q_sid, 0, N - 1))
-            state = state._replace(
-                stats=stats,
-                tenant_queued=tenant_occupancy(state, tables.tenant,
-                                               cfg.n_tenants))
+            with jax.named_scope("fault"):
+                fan = (wi_t.reshape(B, F) >= 0).sum(axis=1, dtype=jnp.int32)
+                fault_evt = fault_events(tables.breaker, badf, wi_t >= 0, t,
+                                         fan, e_pop & e_act, e_row, N)
+                state, stats = fault_phase(
+                    state, stats, tables.breaker, fault_evt, tables.active,
+                    tables.tenant, jnp.clip(state.q_sid, 0, N - 1))
+                state = state._replace(
+                    stats=stats,
+                    tenant_queued=tenant_occupancy(state, tables.tenant,
+                                                   cfg.n_tenants))
             return state, sink
 
         if not jit:
@@ -925,75 +937,84 @@ def make_step(
         stats = dict(state.stats)
 
         # ---- phase 0: ingest external SUs (quota-gate, store, enqueue) --
-        i_sid = jnp.clip(ingest.sid, 0, N - 1)
-        state, stats = ingest_phase(state, stats, ingest, i_sid, i_sid,
-                                    tables.active[i_sid], N,
-                                    tables.tenant[i_sid],
-                                    tables.quota, tables.burst,
-                                    quarantined=state.quarantined[i_sid])
+        with jax.named_scope("ingest"):
+            i_sid = jnp.clip(ingest.sid, 0, N - 1)
+            state, stats = ingest_phase(state, stats, ingest, i_sid, i_sid,
+                                        tables.active[i_sid], N,
+                                        tables.tenant[i_sid],
+                                        tables.quota, tables.burst,
+                                        quarantined=state.quarantined[i_sid])
 
         # ---- pop this round's events (weighted-fair across tenants) -----
-        state, (e_sid, e_vals, e_ts, e_its, e_pop) = _pop(
-            state, tables.priority, B, tables.tenant, tables.weight,
-            cfg.scheduler)
-        stats["popped"] += e_pop.sum(dtype=jnp.int32)
-        # events whose stream was revoked/quarantined while queued drop here
-        e_row = jnp.clip(e_sid, 0, N - 1)
-        e_real = tables.active[e_row]
-        e_act = e_real & ~state.quarantined[e_row]
-        e_valid = e_pop & e_act
-        e_poison = e_pop & e_real & state.quarantined[e_row]
-        stats["dropped_revoked"] += (e_pop & ~e_real).sum(dtype=jnp.int32)
-        state = dlq_append(state, e_sid, e_vals, e_ts,
-                           tables.tenant[e_row],
-                           DLQ_REVOKED, e_pop & ~e_real, its=e_its)
-        stats["dropped_poisoned"] += e_poison.sum(dtype=jnp.int32)
-        state = dlq_append(state, e_sid, e_vals, e_ts,
-                           tables.tenant[e_row],
-                           DLQ_POISONED, e_poison, its=e_its)
+        with jax.named_scope("pop_accounting"):
+            state, (e_sid, e_vals, e_ts, e_its, e_pop) = _pop(
+                state, tables.priority, B, tables.tenant, tables.weight,
+                cfg.scheduler)
+            stats["popped"] += e_pop.sum(dtype=jnp.int32)
+            # events whose stream was revoked/quarantined while queued
+            # drop here
+            e_row = jnp.clip(e_sid, 0, N - 1)
+            e_real = tables.active[e_row]
+            e_act = e_real & ~state.quarantined[e_row]
+            e_valid = e_pop & e_act
+            e_poison = e_pop & e_real & state.quarantined[e_row]
+            stats["dropped_revoked"] += (e_pop & ~e_real).sum(dtype=jnp.int32)
+            state = dlq_append(state, e_sid, e_vals, e_ts,
+                               tables.tenant[e_row],
+                               DLQ_REVOKED, e_pop & ~e_real, its=e_its)
+            stats["dropped_poisoned"] += e_poison.sum(dtype=jnp.int32)
+            state = dlq_append(state, e_sid, e_vals, e_ts,
+                               tables.tenant[e_row],
+                               DLQ_POISONED, e_poison, its=e_its)
 
         # ---- stage 1: subscriber dispatching ----------------------------
         # The engine applies the stale check in process_work_items'
         # keep_mask, so it asks the fanout for targets only — the Pallas
         # stream_dispatch path then skips its timestamp gather.
-        targets, _ = fanout_fn(e_sid, e_ts, e_valid,
-                               tables.out_table, state.timestamps,
-                               with_early=False)
-        wi_t = targets.reshape(W)
-        wi_valid = (wi_t >= 0) & jnp.repeat(e_valid, F)
-        wi_src = jnp.repeat(e_sid, F)
-        wi_vals = jnp.repeat(e_vals, F, axis=0)
-        wi_ts = jnp.repeat(e_ts, F)
-        wi_its = jnp.repeat(e_its, F)
-        t = jnp.clip(wi_t, 0, N - 1)
+        with jax.named_scope("fanout"):
+            targets, _ = fanout_fn(e_sid, e_ts, e_valid,
+                                   tables.out_table, state.timestamps,
+                                   with_early=False)
+            wi_t = targets.reshape(W)
+            wi_valid = (wi_t >= 0) & jnp.repeat(e_valid, F)
+            wi_src = jnp.repeat(e_sid, F)
+            wi_vals = jnp.repeat(e_vals, F, axis=0)
+            wi_ts = jnp.repeat(e_ts, F)
+            wi_its = jnp.repeat(e_its, F)
+            t = jnp.clip(wi_t, 0, N - 1)
 
         # ---- stages 2 + 3: fetch, transform, filter ----------------------
         # the effective active mask (real & ~quarantined) gates the live
         # verdict, so a quarantined *target* cannot run or win either —
         # exactly the mask the fused kernel saw
-        new_vals, ts_out, live, keep, counts, badf = process_work_items(
-            cfg, tables._replace(active=tables.active & ~state.quarantined),
-            t, t, wi_src, wi_vals, wi_ts, wi_valid,
-            state.values, state.timestamps)
-        for k, v in counts.items():
-            stats[k] = stats[k] + v
+        with jax.named_scope("apply"):
+            new_vals, ts_out, live, keep, counts, badf = process_work_items(
+                cfg,
+                tables._replace(active=tables.active & ~state.quarantined),
+                t, t, wi_src, wi_vals, wi_ts, wi_valid,
+                state.values, state.timestamps)
+            for k, v in counts.items():
+                stats[k] = stats[k] + v
 
         # ---- stage 4: store, trigger actions and emit ---------------------
-        state, stats, sink = store_and_emit(cfg, tables, state, stats,
-                                            t, t, wi_src, new_vals, ts_out,
-                                            keep, N, wi_its=wi_its)
+        with jax.named_scope("store_emit"):
+            state, stats, sink = store_and_emit(cfg, tables, state, stats,
+                                                t, t, wi_src, new_vals,
+                                                ts_out, keep, N,
+                                                wi_its=wi_its)
 
         # ---- fault plane: breaker window + device auto-quarantine --------
-        fan = (wi_t.reshape(B, F) >= 0).sum(axis=1, dtype=jnp.int32)
-        fault_evt = fault_events(tables.breaker, badf, wi_valid, t,
-                                 fan, e_valid, e_row, N)
-        state, stats = fault_phase(
-            state, stats, tables.breaker, fault_evt, tables.active,
-            tables.tenant, jnp.clip(state.q_sid, 0, N - 1))
-        state = state._replace(
-            stats=stats,
-            tenant_queued=tenant_occupancy(state, tables.tenant,
-                                           cfg.n_tenants))
+        with jax.named_scope("fault"):
+            fan = (wi_t.reshape(B, F) >= 0).sum(axis=1, dtype=jnp.int32)
+            fault_evt = fault_events(tables.breaker, badf, wi_valid, t,
+                                     fan, e_valid, e_row, N)
+            state, stats = fault_phase(
+                state, stats, tables.breaker, fault_evt, tables.active,
+                tables.tenant, jnp.clip(state.q_sid, 0, N - 1))
+            state = state._replace(
+                stats=stats,
+                tenant_queued=tenant_occupancy(state, tables.tenant,
+                                               cfg.n_tenants))
         return state, sink
 
     if not jit:
@@ -1145,27 +1166,33 @@ def scan_rounds(round_fn: Callable, state: EngineState, ring: IngestRing,
     consumed ring slots.  ``round_fn(state, ingest) -> (state, sink)``.
     ``tenant_by_sid`` (indexed by sink sids) attributes spool-overflow
     dead letters to their emitting tenant."""
-    grid = ring_grid(ring, K, B, C)
+    with jax.named_scope("ring_grid"):
+        grid = ring_grid(ring, K, B, C)
 
     def body(carry, xs):
         st, sp = carry
         k, ingest = xs
         st, sink = round_fn(st, ingest)
-        sp, over = spool_append(sp, sink, k)
-        stats = dict(st.stats)
-        stats["dropped_spool"] = stats["dropped_spool"] + \
-            over.sum(dtype=jnp.int32)
-        st = st._replace(stats=stats)
-        s_ten = None if tenant_by_sid is None else tenant_by_sid[
-            jnp.clip(sink.sid, 0, tenant_by_sid.shape[0] - 1)]
-        st = dlq_append(st, sink.sid, sink.vals, sink.ts, s_ten,
-                        DLQ_SPOOL, over, its=sink.its)
+        with jax.named_scope("spool_append"):
+            sp, over = spool_append(sp, sink, k)
+            stats = dict(st.stats)
+            stats["dropped_spool"] = stats["dropped_spool"] + \
+                over.sum(dtype=jnp.int32)
+            st = st._replace(stats=stats)
+            s_ten = None if tenant_by_sid is None else tenant_by_sid[
+                jnp.clip(sink.sid, 0, tenant_by_sid.shape[0] - 1)]
+            st = dlq_append(st, sink.sid, sink.vals, sink.ts, s_ten,
+                            DLQ_SPOOL, over, its=sink.its)
         return (st, sp), None
 
-    (state, spool), _ = jax.lax.scan(
-        body, (state, _init_spool(P, C)),
-        (jnp.arange(K, dtype=jnp.int32), grid))
-    return state, spool, ring._replace(valid=ring.valid & (ring.rnd >= K))
+    # the loop's own work (slicing the grid, carrying the state) falls
+    # under `round_loop`; the round's stages under their own scopes
+    with jax.named_scope("round_loop"):
+        (state, spool), _ = jax.lax.scan(
+            body, (state, _init_spool(P, C)),
+            (jnp.arange(K, dtype=jnp.int32), grid))
+        return state, spool, ring._replace(
+            valid=ring.valid & (ring.rnd >= K))
 
 
 def make_superstep(
@@ -1425,10 +1452,12 @@ class StreamEngine:
                 self.cfg, K, self._fanout_fn, fused=self._path == "fused")
         return fn
 
-    def _stage(self, K: int) -> None:
+    def _stage(self, K: int) -> Tuple[int, int, int]:
         """Superstep boundary: assign rounds, ship new payloads into free
         ring slots, rewrite every slot's routing tag — one jitted edit.
-        SUs already resident (the overflow queue) are only re-tagged."""
+        SUs already resident (the overflow queue) are only re-tagged.
+        Returns the counts the ``repro.stage`` span carries: SUs assigned
+        to the (K, B) grid, payloads shipped, SUs left pending."""
         R, C = self.cfg.ring_slots(K), self.cfg.channels
         if self._ring is None or self._ring_K != K:
             self._ring, self._ring_K = init_ring(self.cfg, K), K
@@ -1475,15 +1504,24 @@ class StreamEngine:
         self._ring = stage_ring(self._ring, w_slot, w_sid, w_vals, w_ts,
                                 w_its, rnd, pos, valid)
         self._ring_free += [e[3] for e, _k, _i in assigned]
+        return len(assigned), len(writes), len(self._pending)
 
     def superstep(self, K: Optional[int] = None) -> SinkSpool:
         """Run K fused rounds: stage the ingest ring, execute the compiled
         scan, return the sink spool (read it back with ``spool_sinks`` or
-        feed it to the serving bridge's ``pump_spool``)."""
+        feed it to the serving bridge's ``pump_spool``).
+
+        Host spans (``jax.profiler.TraceAnnotation``, inert without an
+        active profiler): ``repro.stage`` around the staging, carrying its
+        ``sus``/``shipped``/``carried`` counts, and ``repro.dispatch``
+        around the superstep program call."""
         K = K or self.cfg.superstep
-        self._stage(K)
+        with jax.profiler.TraceAnnotation("repro.stage") as span:
+            sus, shipped, carried = self._stage(K)
+            span.set_metadata(sus=sus, shipped=shipped, carried=carried)
         self._last_base = self._rounds_done
-        spool = self._run_superstep(K)
+        with jax.profiler.TraceAnnotation("repro.dispatch"):
+            spool = self._run_superstep(K)
         self._rounds_done += K
         self._maybe_checkpoint()
         return spool
@@ -1498,30 +1536,35 @@ class StreamEngine:
                     K: Optional[int] = None) -> List[SinkBatch]:
         """Reconstruct one superstep's per-round :class:`SinkBatch` list
         from the spool — bit-identical to K sequential ``round()`` sinks
-        (provided the spool did not overflow)."""
+        (provided the spool did not overflow).  Host spans:
+        ``repro.spool.read`` (the device-to-host copies, carrying the
+        spooled ``records``) and ``repro.spool.decode`` (the rebuild)."""
         S, C = self.cfg.sink_buffer, self.cfg.channels
-        sid = np.asarray(spool.sid)
-        vals = np.asarray(spool.vals)
-        ts = np.asarray(spool.ts)
-        its = np.asarray(spool.its)
-        rnd = np.asarray(spool.rnd)
-        fill = int(spool.fill)
+        with jax.profiler.TraceAnnotation("repro.spool.read") as span:
+            sid = np.asarray(spool.sid)
+            vals = np.asarray(spool.vals)
+            ts = np.asarray(spool.ts)
+            its = np.asarray(spool.its)
+            rnd = np.asarray(spool.rnd)
+            fill = int(spool.fill)
+            span.set_metadata(records=fill)
         K = K or self._ring_K or (int(rnd[:fill].max()) + 1 if fill else 1)
         sinks = []
-        for k in range(K):
-            b_sid = np.zeros((S,), np.int32)
-            b_vals = np.zeros((S, C), np.float32)
-            b_ts = np.zeros((S,), np.int32)
-            b_valid = np.zeros((S,), bool)
-            b_its = np.zeros((S,), np.int32)
-            idx = np.nonzero(rnd[:fill] == k)[0]
-            n = len(idx)
-            b_sid[:n], b_vals[:n], b_ts[:n] = sid[idx], vals[idx], ts[idx]
-            b_its[:n] = its[idx]
-            b_valid[:n] = True
-            # host arrays: the spool was already read back, consumers read
-            # these with np.asarray — no device round-trip
-            sinks.append(SinkBatch(b_sid, b_vals, b_ts, b_valid, b_its))
+        with jax.profiler.TraceAnnotation("repro.spool.decode"):
+            for k in range(K):
+                b_sid = np.zeros((S,), np.int32)
+                b_vals = np.zeros((S, C), np.float32)
+                b_ts = np.zeros((S,), np.int32)
+                b_valid = np.zeros((S,), bool)
+                b_its = np.zeros((S,), np.int32)
+                idx = np.nonzero(rnd[:fill] == k)[0]
+                n = len(idx)
+                b_sid[:n], b_vals[:n], b_ts[:n] = sid[idx], vals[idx], ts[idx]
+                b_its[:n] = its[idx]
+                b_valid[:n] = True
+                # host arrays: the spool was already read back, consumers
+                # read these with np.asarray — no device round-trip
+                sinks.append(SinkBatch(b_sid, b_vals, b_ts, b_valid, b_its))
         return sinks
 
     def latency_records(self, source, base: Optional[int] = None

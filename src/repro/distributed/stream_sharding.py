@@ -373,7 +373,9 @@ def reshard_snapshot(arrays, meta, n_shards: int,
                 int(d_its[s, i]), int(d_reason[s, i]), int(d_tenant[s, i]))
                for s in range(d_sid.shape[0]) for i in range(int(d_fill[s]))]
 
-    totals = {k: tot(arrays[f"state/stats/{k}"]) for k in STAT_KEYS}
+    stat0 = np.zeros_like(np.asarray(arrays["state/stats/ingested"]))
+    totals = {k: tot(arrays.get(f"state/stats/{k}", stat0))
+              for k in STAT_KEYS}     # a key the snapshot predates reads 0
     t_emitted = tot(arrays["state/tenant_emitted"])
     t_drop_quota = tot(arrays["state/tenant_dropped_quota"])
     t_drop_over = tot(arrays["state/tenant_dropped_overflow"])
@@ -567,159 +569,178 @@ def make_shard_round(
 
         # ---- phase 0: ingest SUs routed to this shard (global sids),
         # quota-gated against this shard's token buckets ------------------
-        g_sid = jnp.clip(ingest.sid, 0, N - 1)
-        l_sid = jnp.clip(gmap.sid_to_local[g_sid], 0, n_local - 1)
-        state, stats = ingest_phase(state, stats, ingest, l_sid, g_sid,
-                                    tables.active[l_sid], n_local,
-                                    tables.tenant[l_sid],
-                                    tables.quota, tables.burst,
-                                    fast_free=fused,
-                                    quarantined=state.quarantined[l_sid])
+        with jax.named_scope("ingest"):
+            g_sid = jnp.clip(ingest.sid, 0, N - 1)
+            l_sid = jnp.clip(gmap.sid_to_local[g_sid], 0, n_local - 1)
+            state, stats = ingest_phase(state, stats, ingest, l_sid, g_sid,
+                                        tables.active[l_sid], n_local,
+                                        tables.tenant[l_sid],
+                                        tables.quota, tables.burst,
+                                        fast_free=fused,
+                                        quarantined=state.quarantined[l_sid])
 
         # ---- pop this round's events (weighted-fair; global sids) -------
-        state, (e_sid, e_vals, e_ts, e_its, e_pop) = _pop(
-            state, gmap.priority, B, tenant_by_sid, tables.weight,
-            cfg.scheduler)
-        stats["popped"] += e_pop.sum(dtype=jnp.int32)
-        e_loc = jnp.clip(gmap.sid_to_local[jnp.clip(e_sid, 0, N - 1)],
-                         0, n_local - 1)
-        # events whose stream was revoked (or quarantined) while queued
-        # drop here; the two classes are accounted separately
-        e_act = tables.active[e_loc]
-        e_poison = e_pop & e_act & state.quarantined[e_loc]
-        e_valid = e_pop & e_act & ~state.quarantined[e_loc]
-        stats["dropped_revoked"] += (e_pop & ~e_act).sum(dtype=jnp.int32)
-        state = dlq_append(state, e_sid, e_vals, e_ts,
-                           tenant_by_sid[jnp.clip(e_sid, 0, N - 1)],
-                           DLQ_REVOKED, e_pop & ~e_act, its=e_its)
-        stats["dropped_poisoned"] += e_poison.sum(dtype=jnp.int32)
-        state = dlq_append(state, e_sid, e_vals, e_ts,
-                           tenant_by_sid[jnp.clip(e_sid, 0, N - 1)],
-                           DLQ_POISONED, e_poison, its=e_its)
+        with jax.named_scope("pop_accounting"):
+            state, (e_sid, e_vals, e_ts, e_its, e_pop) = _pop(
+                state, gmap.priority, B, tenant_by_sid, tables.weight,
+                cfg.scheduler)
+            stats["popped"] += e_pop.sum(dtype=jnp.int32)
+            e_loc = jnp.clip(gmap.sid_to_local[jnp.clip(e_sid, 0, N - 1)],
+                             0, n_local - 1)
+            # events whose stream was revoked (or quarantined) while queued
+            # drop here; the two classes are accounted separately
+            e_act = tables.active[e_loc]
+            e_poison = e_pop & e_act & state.quarantined[e_loc]
+            e_valid = e_pop & e_act & ~state.quarantined[e_loc]
+            stats["dropped_revoked"] += (e_pop & ~e_act).sum(dtype=jnp.int32)
+            state = dlq_append(state, e_sid, e_vals, e_ts,
+                               tenant_by_sid[jnp.clip(e_sid, 0, N - 1)],
+                               DLQ_REVOKED, e_pop & ~e_act, its=e_its)
+            stats["dropped_poisoned"] += e_poison.sum(dtype=jnp.int32)
+            state = dlq_append(state, e_sid, e_vals, e_ts,
+                               tenant_by_sid[jnp.clip(e_sid, 0, N - 1)],
+                               DLQ_POISONED, e_poison, its=e_its)
 
         # ---- post-ingest snapshot: the lock-free global view ------------
-        vals_all = jax.lax.all_gather(state.values, AXIS)
-        ts_all = jax.lax.all_gather(state.timestamps, AXIS)
-        values_by_sid = vals_all.reshape(n_shards * n_local, C)[gmap.sid_to_flat]
-        ts_by_sid = ts_all.reshape(n_shards * n_local)[gmap.sid_to_flat]
+        with jax.named_scope("snapshot"):
+            vals_all = jax.lax.all_gather(state.values, AXIS)
+            ts_all = jax.lax.all_gather(state.timestamps, AXIS)
+            values_by_sid = vals_all.reshape(n_shards * n_local,
+                                             C)[gmap.sid_to_flat]
+            ts_by_sid = ts_all.reshape(n_shards * n_local)[gmap.sid_to_flat]
 
         # ---- stage 1: fan-out via the shard-local out-tables ------------
-        targets, _ = fanout_fn(e_loc, e_ts, e_valid,
-                               tables.out_table, ts_by_sid,
-                               with_early=False)
-        wi_t = targets.reshape(W)
-        wi_valid = (wi_t >= 0) & jnp.repeat(e_valid, F)
-        wi_src = jnp.repeat(e_sid, F)
-        wi_vals = jnp.repeat(e_vals, F, axis=0)
-        wi_ts = jnp.repeat(e_ts, F)
-        wi_its = jnp.repeat(e_its, F)
+        with jax.named_scope("fanout"):
+            targets, _ = fanout_fn(e_loc, e_ts, e_valid,
+                                   tables.out_table, ts_by_sid,
+                                   with_early=False)
+            wi_t = targets.reshape(W)
+            wi_valid = (wi_t >= 0) & jnp.repeat(e_valid, F)
+            wi_src = jnp.repeat(e_sid, F)
+            wi_vals = jnp.repeat(e_vals, F, axis=0)
+            wi_ts = jnp.repeat(e_ts, F)
+            wi_its = jnp.repeat(e_its, F)
 
         # ---- exchange stage: route work items to the target's owner -----
         # One-pass compaction: a single running per-destination count gives
         # every item its rank within its destination bucket, then one
         # scatter packs all buckets at once (slot layout — and therefore
         # results — bit-identical to the former per-destination loop).
-        t_safe = jnp.clip(wi_t, 0, N - 1)
-        dest_shard = jnp.where(wi_valid, gmap.sid_to_shard[t_safe], n_shards)
-        if fused:
-            xi, xf, x_drop = exchange_compact(wi_t, wi_src, wi_ts, wi_its,
-                                              wi_vals, dest_shard,
-                                              n_shards, E)
-        else:
-            payload_i = jnp.stack([wi_t, wi_src, wi_ts, wi_its],
-                                  axis=-1)                           # (W, 4)
-            routed = dest_shard < n_shards
-            d_safe = jnp.clip(dest_shard, 0, n_shards - 1)
-            # unrouted items must not consume bucket ranks: mask them out
-            # of the running count (their own rank reads garbage, gated)
-            onehot = routed[:, None] & \
-                (d_safe[:, None] == jnp.arange(n_shards)[None, :])   # (W, D)
-            rank = jnp.take_along_axis(
-                jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1,
-                d_safe[:, None], axis=1)[:, 0]                       # (W,)
-            fits = routed & (rank < E)
-            slot = jnp.where(fits, d_safe * E + rank, n_shards * E)
-            xi = jnp.full((n_shards * E, 4), -1, jnp.int32) \
-                .at[slot].set(payload_i, mode="drop").reshape(n_shards, E, 4)
-            xf = jnp.zeros((n_shards * E, C), jnp.float32) \
-                .at[slot].set(wi_vals, mode="drop").reshape(n_shards, E, C)
-            x_drop = routed & ~fits
-        stats["dropped_overflow"] += x_drop.sum(dtype=jnp.int32)
-        # exchange-slot contention is attributable per tenant: charge the
-        # *emitting* stream's owner (wi_src is always owned by this shard,
-        # so the local tenant map resolves it; the flooding producer pays,
-        # consistent with queue-overflow and quota accounting)
-        Tn = cfg.n_tenants
-        src_safe = jnp.clip(wi_src, 0, N - 1)
-        state = state._replace(
-            tenant_dropped_overflow=state.tenant_dropped_overflow.at[
-                jnp.where(x_drop, tenant_by_sid[src_safe], Tn)
-            ].add(1, mode="drop"))
-        state = dlq_append(state, wi_src, wi_vals, wi_ts,
-                           tenant_by_sid[src_safe], DLQ_OVERFLOW, x_drop,
-                           its=wi_its)
+        with jax.named_scope("exchange"):
+            t_safe = jnp.clip(wi_t, 0, N - 1)
+            dest_shard = jnp.where(wi_valid, gmap.sid_to_shard[t_safe],
+                                   n_shards)
+            if fused:
+                xi, xf, x_drop = exchange_compact(wi_t, wi_src, wi_ts,
+                                                  wi_its, wi_vals,
+                                                  dest_shard, n_shards, E)
+            else:
+                payload_i = jnp.stack([wi_t, wi_src, wi_ts, wi_its],
+                                      axis=-1)                       # (W, 4)
+                routed = dest_shard < n_shards
+                d_safe = jnp.clip(dest_shard, 0, n_shards - 1)
+                # unrouted items must not consume bucket ranks: mask them
+                # out of the running count (their own rank reads garbage,
+                # gated)
+                onehot = routed[:, None] & \
+                    (d_safe[:, None] == jnp.arange(n_shards)[None, :])
+                rank = jnp.take_along_axis(
+                    jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1,
+                    d_safe[:, None], axis=1)[:, 0]                   # (W,)
+                fits = routed & (rank < E)
+                slot = jnp.where(fits, d_safe * E + rank, n_shards * E)
+                xi = jnp.full((n_shards * E, 4), -1, jnp.int32) \
+                    .at[slot].set(payload_i, mode="drop") \
+                    .reshape(n_shards, E, 4)
+                xf = jnp.zeros((n_shards * E, C), jnp.float32) \
+                    .at[slot].set(wi_vals, mode="drop") \
+                    .reshape(n_shards, E, C)
+                x_drop = routed & ~fits
+            stats["dropped_overflow"] += x_drop.sum(dtype=jnp.int32)
+            # exchange-slot contention is attributable per tenant: charge
+            # the *emitting* stream's owner (wi_src is always owned by this
+            # shard, so the local tenant map resolves it; the flooding
+            # producer pays, consistent with queue-overflow and quota
+            # accounting)
+            Tn = cfg.n_tenants
+            src_safe = jnp.clip(wi_src, 0, N - 1)
+            state = state._replace(
+                tenant_dropped_overflow=state.tenant_dropped_overflow.at[
+                    jnp.where(x_drop, tenant_by_sid[src_safe], Tn)
+                ].add(1, mode="drop"))
+            state = dlq_append(state, wi_src, wi_vals, wi_ts,
+                               tenant_by_sid[src_safe], DLQ_OVERFLOW, x_drop,
+                               its=wi_its)
 
-        ri = jax.lax.all_to_all(xi, AXIS, split_axis=0, concat_axis=0)
-        rf = jax.lax.all_to_all(xf, AXIS, split_axis=0, concat_axis=0)
-        r_t = ri[..., 0].reshape(WR)
-        r_src = ri[..., 1].reshape(WR)
-        r_ts = ri[..., 2].reshape(WR)
-        r_its = ri[..., 3].reshape(WR)
-        r_vals = rf.reshape(WR, C)
-        r_valid = r_t >= 0
-        rt_safe = jnp.clip(r_t, 0, N - 1)
-        r_loc = jnp.clip(gmap.sid_to_local[rt_safe], 0, n_local - 1)
+            ri = jax.lax.all_to_all(xi, AXIS, split_axis=0, concat_axis=0)
+            rf = jax.lax.all_to_all(xf, AXIS, split_axis=0, concat_axis=0)
+            r_t = ri[..., 0].reshape(WR)
+            r_src = ri[..., 1].reshape(WR)
+            r_ts = ri[..., 2].reshape(WR)
+            r_its = ri[..., 3].reshape(WR)
+            r_vals = rf.reshape(WR, C)
+            r_valid = r_t >= 0
+            rt_safe = jnp.clip(r_t, 0, N - 1)
+            r_loc = jnp.clip(gmap.sid_to_local[rt_safe], 0, n_local - 1)
 
         # ---- stages 2 + 3 (shared with the single-device engine) --------
         # quarantined rows are masked out of the effective active plane, so
         # a poisoned stream neither stores nor emits while tripped
-        eff_active = tables.active & ~state.quarantined
-        if fused:
-            # the local tables (n_local rows) and the global snapshot (N
-            # rows) are two row spaces, which the apply kernel does not
-            # take: this stage runs the fused jnp reference on every backend
-            new_vals, ts_out, live, keep, keep_ts, passf, badf = \
-                apply_programs(layout, tables.in_table, tables.progs,
-                               tables.consts, tables.is_composite,
-                               eff_active, r_loc, rt_safe, r_src,
-                               r_vals, r_ts, r_valid,
-                               values_by_sid, ts_by_sid, use_kernel=False)
-            stats["processed"] += live.sum(dtype=jnp.int32)
-            stats["discarded_stale"] += \
-                (live & ~keep_ts).sum(dtype=jnp.int32)
-            stats["filtered"] += \
-                (live & keep_ts & ~passf).sum(dtype=jnp.int32)
-            stats["nonfinite"] += (badf & r_valid).sum(dtype=jnp.int32)
-        else:
-            new_vals, ts_out, live, keep, counts, badf = process_work_items(
-                cfg, tables._replace(active=eff_active), r_loc, rt_safe,
-                r_src, r_vals, r_ts, r_valid, values_by_sid, ts_by_sid)
-            for k, v in counts.items():
-                stats[k] = stats[k] + v
+        with jax.named_scope("apply"):
+            eff_active = tables.active & ~state.quarantined
+            if fused:
+                # the local tables (n_local rows) and the global snapshot
+                # (N rows) are two row spaces, which the apply kernel does
+                # not take: this stage runs the fused jnp reference on
+                # every backend
+                new_vals, ts_out, live, keep, keep_ts, passf, badf = \
+                    apply_programs(layout, tables.in_table, tables.progs,
+                                   tables.consts, tables.is_composite,
+                                   eff_active, r_loc, rt_safe, r_src,
+                                   r_vals, r_ts, r_valid,
+                                   values_by_sid, ts_by_sid,
+                                   use_kernel=False)
+                stats["processed"] += live.sum(dtype=jnp.int32)
+                stats["discarded_stale"] += \
+                    (live & ~keep_ts).sum(dtype=jnp.int32)
+                stats["filtered"] += \
+                    (live & keep_ts & ~passf).sum(dtype=jnp.int32)
+                stats["nonfinite"] += (badf & r_valid).sum(dtype=jnp.int32)
+            else:
+                new_vals, ts_out, live, keep, counts, badf = \
+                    process_work_items(cfg, tables._replace(active=eff_active),
+                                       r_loc, rt_safe, r_src, r_vals, r_ts,
+                                       r_valid, values_by_sid, ts_by_sid)
+                for k, v in counts.items():
+                    stats[k] = stats[k] + v
 
         # ---- stage 4: store into this shard's slice ----------------------
         # (winners re-enqueue into the local queue; the sink is per-shard)
-        state, stats, sink = store_and_emit(cfg, tables, state, stats,
-                                            r_loc, r_t, r_src, new_vals,
-                                            ts_out, keep, n_local,
-                                            fast_free=fused, wi_its=r_its)
+        with jax.named_scope("store_emit"):
+            state, stats, sink = store_and_emit(cfg, tables, state, stats,
+                                                r_loc, r_t, r_src, new_vals,
+                                                ts_out, keep, n_local,
+                                                fast_free=fused, wi_its=r_its)
 
         # ---- fault plane: breaker window + device auto-quarantine -------
         # amplification is detected at the dispatch site (the source shard
         # owns the popped sid); non-finite results are detected after the
         # exchange on the shard owning the target row — each fault lands
         # on its row's owner, so the breaker state never needs collectives
-        fan = (wi_t.reshape(B, F) >= 0).sum(axis=1, dtype=jnp.int32)
-        fault_evt = fault_events(tables.breaker, badf, r_valid, r_loc,
-                                 fan, e_valid, e_loc, n_local)
-        q_row = jnp.clip(gmap.sid_to_local[jnp.clip(state.q_sid, 0, N - 1)],
-                         0, n_local - 1)
-        state, stats = fault_phase(state, stats, tables.breaker, fault_evt,
-                                   tables.active, tables.tenant, q_row)
-        state = state._replace(
-            stats=stats,
-            tenant_queued=tenant_occupancy(state, tenant_by_sid,
-                                           cfg.n_tenants))
+        with jax.named_scope("fault"):
+            fan = (wi_t.reshape(B, F) >= 0).sum(axis=1, dtype=jnp.int32)
+            fault_evt = fault_events(tables.breaker, badf, r_valid, r_loc,
+                                     fan, e_valid, e_loc, n_local)
+            q_row = jnp.clip(
+                gmap.sid_to_local[jnp.clip(state.q_sid, 0, N - 1)],
+                0, n_local - 1)
+            state, stats = fault_phase(state, stats, tables.breaker,
+                                       fault_evt, tables.active,
+                                       tables.tenant, q_row)
+            state = state._replace(
+                stats=stats,
+                tenant_queued=tenant_occupancy(state, tenant_by_sid,
+                                               cfg.n_tenants))
         return state, sink
 
     return shard_round
@@ -950,7 +971,7 @@ class ShardedStreamEngine(StreamEngine):
         s, j = slot
         self._ring_free[s].append(j)
 
-    def _stage(self, K: int) -> None:
+    def _stage(self, K: int) -> Tuple[int, int, int]:
         """Superstep boundary, sharded: assign rounds exactly like K
         sequential ``_take_ingest`` calls and route every staged SU to
         its owner shard's ring slice.  The per-shard ring layout (and its
@@ -963,7 +984,8 @@ class ShardedStreamEngine(StreamEngine):
         (admission routing, ``rebalance``, ``rewire``) set
         ``_ring_dirty``, which voids the cache — the next boundary
         re-stages everything from the host copy, so a moved sid can
-        never consume a stale shard's slot."""
+        never consume a stale shard's slot.  Returns the
+        ``repro.stage`` span's counts, as the single-device ``_stage``."""
         S, R, C = self.plan.n_shards, self.cfg.ring_slots(K), self.cfg.channels
         N = self.cfg.n_streams
         if self._ring is None or self._ring_K != K or self._ring_dirty:
@@ -1036,6 +1058,7 @@ class ShardedStreamEngine(StreamEngine):
         for e, _k, _i in assigned:            # consumed by this superstep:
             s, j = e[3]                       # slots reusable next boundary
             self._ring_free[s].append(j)
+        return len(assigned), len(writes), len(self._pending)
 
     def _run_superstep(self, K: int) -> SinkSpool:
         self.state, spool, self._ring = self._superstep_fn(K)(
@@ -1044,32 +1067,36 @@ class ShardedStreamEngine(StreamEngine):
 
     def spool_sinks(self, spool: SinkSpool, K=None) -> List[SinkBatch]:
         """Per-round SinkBatches from the per-shard spools — each round's
-        batch is the shard-concatenated layout ``round()`` returns."""
+        batch is the shard-concatenated layout ``round()`` returns.  Host
+        spans as the single-device ``spool_sinks``."""
         S, C = self.cfg.sink_buffer, self.cfg.channels
         n_sh = self.plan.n_shards
-        sid = np.asarray(spool.sid)
-        vals = np.asarray(spool.vals)
-        ts = np.asarray(spool.ts)
-        its = np.asarray(spool.its)
-        rnd = np.asarray(spool.rnd)
-        fill = np.asarray(spool.fill)
+        with jax.profiler.TraceAnnotation("repro.spool.read") as span:
+            sid = np.asarray(spool.sid)
+            vals = np.asarray(spool.vals)
+            ts = np.asarray(spool.ts)
+            its = np.asarray(spool.its)
+            rnd = np.asarray(spool.rnd)
+            fill = np.asarray(spool.fill)
+            span.set_metadata(records=int(fill.sum()))
         K = K or self._ring_K or 1
         sinks = []
-        for k in range(K):
-            b_sid = np.zeros((n_sh * S,), np.int32)
-            b_vals = np.zeros((n_sh * S, C), np.float32)
-            b_ts = np.zeros((n_sh * S,), np.int32)
-            b_valid = np.zeros((n_sh * S,), bool)
-            b_its = np.zeros((n_sh * S,), np.int32)
-            for s in range(n_sh):
-                idx = np.nonzero(rnd[s, :fill[s]] == k)[0]
-                n = len(idx)
-                b_sid[s * S:s * S + n] = sid[s, idx]
-                b_vals[s * S:s * S + n] = vals[s, idx]
-                b_ts[s * S:s * S + n] = ts[s, idx]
-                b_its[s * S:s * S + n] = its[s, idx]
-                b_valid[s * S:s * S + n] = True
-            sinks.append(SinkBatch(b_sid, b_vals, b_ts, b_valid, b_its))
+        with jax.profiler.TraceAnnotation("repro.spool.decode"):
+            for k in range(K):
+                b_sid = np.zeros((n_sh * S,), np.int32)
+                b_vals = np.zeros((n_sh * S, C), np.float32)
+                b_ts = np.zeros((n_sh * S,), np.int32)
+                b_valid = np.zeros((n_sh * S,), bool)
+                b_its = np.zeros((n_sh * S,), np.int32)
+                for s in range(n_sh):
+                    idx = np.nonzero(rnd[s, :fill[s]] == k)[0]
+                    n = len(idx)
+                    b_sid[s * S:s * S + n] = sid[s, idx]
+                    b_vals[s * S:s * S + n] = vals[s, idx]
+                    b_ts[s * S:s * S + n] = ts[s, idx]
+                    b_its[s * S:s * S + n] = its[s, idx]
+                    b_valid[s * S:s * S + n] = True
+                sinks.append(SinkBatch(b_sid, b_vals, b_ts, b_valid, b_its))
         return sinks
 
     # ------------------------------------------------- dynamic admission

@@ -445,6 +445,7 @@ def fused_round_call(prio_slot, seq, valid, t_slot, w_slot, sid, vals, ts,
             jax.ShapeDtypeStruct((W, 1), i32b),           # badf
         ),
         compiler_params=_PARAMS,
+        name="fused_round",
         interpret=interpret,
     )(qrow(prio_slot), qrow(seq), qrow(valid), qlive, qrow(t_slot),
       qrow(w_slot), qrow(sid), qrow(ts),
@@ -522,6 +523,7 @@ def apply_programs_call(layout: RegLayout, in_table, progs, consts,
             jax.ShapeDtypeStruct((W, 1), i32b),           # badf
         ),
         compiler_params=_PARAMS,
+        name="apply_programs",
         interpret=interpret,
     )(wcol(rows), wcol(t_sid), wcol(wi_src),
       jnp.asarray(wi_vals, jnp.float32), wcol(wi_ts), wcol(wi_valid),
@@ -604,6 +606,7 @@ def exchange_compact_call(wi_t, wi_src, wi_ts, wi_its, wi_vals, dest_shard,
             jax.ShapeDtypeStruct((1, Wp), jnp.int32),
         ),
         compiler_params=_PARAMS,
+        name="exchange_compact",
         interpret=interpret,
     )(wrow(wi_t), wrow(wi_src), wrow(wi_ts), wrow(wi_its),
       jnp.pad(jnp.asarray(wi_vals, jnp.float32), ((0, Wp - W), (0, 0))),

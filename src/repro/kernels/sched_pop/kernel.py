@@ -124,6 +124,7 @@ def sched_pop_call(prio, seq, valid, tenant, w_slot, sid, vals, ts,
             jax.ShapeDtypeStruct((1, batch), jnp.int32),   # p_valid
             jax.ShapeDtypeStruct((batch, C), jnp.float32), # p_vals
         ),
+        name="sched_pop",
         interpret=interpret,
     )(i32row(prio), i32row(seq), i32row(valid), live, i32row(tenant),
       i32row(w_slot), i32row(sid), i32row(ts),

@@ -60,6 +60,7 @@ def onehot_gather(table: jnp.ndarray, ids: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((bm, F), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, F), jnp.float32),
+        name="stream_dispatch",
         interpret=interpret,
     )(ids_p, table_p)
     return out[:M]

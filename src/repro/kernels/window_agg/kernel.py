@@ -54,6 +54,7 @@ def window_agg(values: jnp.ndarray, count: jnp.ndarray, *,
         ],
         out_specs=[pl.BlockSpec((bn, C), lambda i: (i, 0))] * 5,
         out_shape=[jax.ShapeDtypeStruct((N, C), jnp.float32)] * 5,
+        name="window_agg",
         interpret=interpret,
     )(jnp.transpose(values, (1, 0, 2)), count.reshape(N, 1))
     return dict(zip(("sum", "mean", "max", "min", "count"), outs))

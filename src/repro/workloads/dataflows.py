@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -122,15 +123,23 @@ class WindowedStats:
 
     def push_sink(self, sink) -> None:
         """Fold one per-round :class:`SinkBatch` (any shard layout — the
-        planes are flattened) into the window."""
-        sid = np.asarray(sink.sid).reshape(-1)
-        vals = np.asarray(sink.vals).reshape(-1, np.asarray(sink.vals).shape[-1])
-        ts = np.asarray(sink.ts).reshape(-1)
-        valid = np.asarray(sink.valid).reshape(-1)
-        C = self.store.values.shape[-1]
-        self.store = push(self.store, jnp.asarray(sid),
+        planes are flattened) into the window.  Host spans:
+        ``repro.stats.push`` (the whole fold, carrying its valid ``rows``)
+        and, inside it, ``repro.stats.upload`` (the host-to-device
+        copies)."""
+        with jax.profiler.TraceAnnotation("repro.stats.push") as span:
+            sid = np.asarray(sink.sid).reshape(-1)
+            vals = np.asarray(sink.vals).reshape(
+                -1, np.asarray(sink.vals).shape[-1])
+            ts = np.asarray(sink.ts).reshape(-1)
+            valid = np.asarray(sink.valid).reshape(-1)
+            span.set_metadata(rows=int(valid.sum()))
+            C = self.store.values.shape[-1]
+            with jax.profiler.TraceAnnotation("repro.stats.upload"):
+                planes = (jnp.asarray(sid),
                           jnp.asarray(vals[:, :C], jnp.float32),
                           jnp.asarray(ts, jnp.int32), jnp.asarray(valid))
+            self.store = push(self.store, *planes)
 
     def push_spool(self, engine, spool) -> None:
         for sink in engine.spool_sinks(spool):
@@ -139,5 +148,6 @@ class WindowedStats:
     def aggregates(self, horizon: Optional[int] = None
                    ) -> Dict[str, jnp.ndarray]:
         """Windowed sum/mean/max/min/count per stream, via the
-        ``window_agg`` kernel path."""
-        return aggregate(self.store, horizon=horizon)
+        ``window_agg`` kernel path (host span ``repro.stats.aggregate``)."""
+        with jax.profiler.TraceAnnotation("repro.stats.aggregate"):
+            return aggregate(self.store, horizon=horizon)
