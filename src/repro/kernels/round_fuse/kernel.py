@@ -3,20 +3,25 @@ sharded exchange compaction.
 
 ``_fused_round_kernel`` keeps one round's winners in VMEM end to end:
 the ``sched_pop`` selection loop picks the top-``batch`` queue slots,
-each winner's subscriber row / active flag are gathered in the same
-loop step, the fan-out work items are formed in registers, co-inputs
-are fetched, the reduced-branch VM runs as a vectorized select tree,
-and the Listing-2 window/consistency verdict is computed — all before
-anything is written back to HBM.  The staged round lowers the same
-dataflow as five XLA ops with an HBM round-trip between each.
+one winner per step, carrying only what decides the next winner (the
+key planes, the taken mask, the winners' slots and valid bits).  After
+the loop, every winner's payload (sid, ts, value bits), subscriber row
+and active flag are gathered at once by masked lane sums, the fan-out
+work items are formed by a one-hot expansion, co-inputs are fetched,
+the reduced-branch VM runs as a vectorized select tree, and the
+Listing-2 window/consistency verdict is computed — all before anything
+is written back to HBM.  The staged round lowers the same dataflow as
+five XLA ops with an HBM round-trip between each.
 
-Gather idiom: every row fetch is a one-hot matmul on the MXU.  A
-one-hot f32 matmul is exact only for values a float32 represents
-exactly, so int32 planes (and float payloads, which ride as their
-bits) are gathered as split 16-bit halves — ``hi = x >> 16`` and
-``lo = x & 0xffff`` both fit f32's 24-bit mantissa — and recombined
-(the ``stream_dispatch`` timestamp trick, generalized).  Exact at any
-bit pattern, sign of zero and NaN payloads included.
+Gather idiom: a row fetch is a one-hot matmul on the MXU, or, where
+the table is laid out as rows (the queue's payload planes, the
+subscriber table), a masked lane sum in int32.  A one-hot f32 matmul is
+exact only for values a float32 represents exactly, so int32 planes
+(and float payloads, which ride as their bits) are gathered as split
+16-bit halves — ``hi = x >> 16`` and ``lo = x & 0xffff`` both fit f32's
+24-bit mantissa — and recombined (the ``stream_dispatch`` timestamp
+trick, generalized).  Both are exact at any bit pattern, sign of zero
+and NaN payloads included.
 
 The MXU passes ask for ``Precision.HIGHEST``: a default-precision f32
 matmul rounds its operands toward bf16 (8 mantissa bits), which does
@@ -24,19 +29,24 @@ not carry a 16-bit half exactly — on a v5e chip the fused round lost
 sink records without it.
 
 VMEM sizing: the dominant intermediates are the (W, N') one-hot gather
-operands and the (W, R) register file, W = batch*max_out work lanes,
-N' = n_streams padded to 128, R = n_regs; narrow (N', 1..16) table
-columns also occupy whole 128-lane tiles.  At the 1,024-tenant IoT
-deployment (W = 512, N' = 3,712) the v5e compiler asks for 94.27 MiB of
-scoped VMEM for the fused kernel (93.47 MiB for the apply kernel)
+operands and the (W, R) register file of stages 2-3, W = batch*max_out
+work lanes, N' = n_streams padded to 128, R = n_regs; narrow (N', 1..16)
+table columns also occupy whole 128-lane tiles.  The winners' gathers
+take (batch, ``_LANE_CHUNK``) masks, a loop step at a time.  At the
+1,024-tenant IoT deployment (Q = 8,192, W = 512, N' = 3,712: the shapes
+of ``tests/test_tpu_compile.py``) the v5e compiler asks for 93.18 MiB
+of scoped VMEM for the fused kernel (93.47 MiB for the apply kernel)
 against Mosaic's default 16 MiB, so every kernel here raises the limit
 to ``_VMEM_LIMIT`` (v5e has 128 MiB per core).  That deployment then
-runs on the chip, with about 6 MiB to spare: a larger W * N' does not
+runs on the chip, with about 7 MiB to spare: a larger W * N' does not
 fit and should keep ``fused_round`` off.  The ``HIGHEST`` gathers take
-most of it: with default-precision dots the same kernels ask for
-21.39 / 21.52 MiB.  Code size and compile time grow with W * N' too
-(~30 MB of kernel code at that size; the fused superstep compiles in
-70-80 s on the chip).
+most of it: with default-precision dots the apply kernel asks for
+21.52 MiB, and the winners' (batch, N') gathers as ``HIGHEST`` matmuls
+in place of masked sums took the fused kernel's ask to 105.58 MiB.
+(An ask is the size the compiler reports when it refuses a smaller
+limit.)  Code size and compile time grow with W * N' too (~30 MB of kernel
+code at that size; the fused superstep compiles in 70-80 s on the
+chip).
 """
 from __future__ import annotations
 
@@ -59,6 +69,8 @@ _EPS = pvm._EPS
 # deployment stream counts; v5e has 128 MiB of VMEM per core.
 _VMEM_LIMIT = 100 * 2 ** 20
 _PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+# Lanes per loop step of ``_lane_gather``.
+_LANE_CHUNK = 512
 
 
 # --------------------------------------------------------------------------
@@ -87,6 +99,40 @@ def _gather_f32(onehot: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """Exact float32 row gather: floats ride as their bits."""
     bits = _gather_i32(onehot, jax.lax.bitcast_convert_type(table, jnp.int32))
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _to_col(row: jnp.ndarray) -> jnp.ndarray:
+    """(1, n) int32 row -> (n, 1) column, as a masked lane sum over the
+    diagonal of its broadcast (exact: one term per row)."""
+    n = row.shape[1]
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(diag, row, 0), axis=1, keepdims=True)
+
+
+def _lane_gather(idx_col: jnp.ndarray, rows_ref) -> list:
+    """Exact int32 gather from a table laid out as rows: entry k of the
+    result is the (W, 1) column ``rows_ref[k, idx_col]`` (0 where an
+    index is out of range).  Masked lane sums, ``_LANE_CHUNK`` lanes a
+    loop step, so the code and the (W, chunk) temporaries stay small."""
+    W = idx_col.shape[0]
+    X, n = rows_ref.shape
+
+    def part(lo, size, acc):
+        hit = (jax.lax.broadcasted_iota(jnp.int32, (W, size), 1) + lo
+               == idx_col)
+        def row(k):
+            return jnp.where(hit, rows_ref[k:k + 1, pl.ds(lo, size)], 0)
+        return [a + jnp.sum(row(k), axis=1, keepdims=True)
+                for k, a in enumerate(acc)]
+
+    acc = [jnp.zeros((W, 1), jnp.int32)] * X
+    n_full, rem = divmod(n, _LANE_CHUNK)
+    if n_full:
+        acc = jax.lax.fori_loop(0, n_full, lambda c, acc: part(
+            pl.multiple_of(c * _LANE_CHUNK, _LANE_CHUNK), _LANE_CHUNK, acc),
+            acc)
+    return part(n - rem, rem, acc) if rem else acc
 
 
 def _lane_f32(mask: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
@@ -258,8 +304,8 @@ def _pack_apply_outputs(outs, refs):
 # --------------------------------------------------------------------------
 
 def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
-                        w_ref, sid_ref, ts_ref, qvals_ref,
-                        out_tbl_ref, in_tbl_ref, progs_ref, consts_ref,
+                        w_ref, pay_ref, sub_ref,
+                        in_tbl_ref, progs_ref, consts_ref,
                         comp_ref, act_ref, values_ref, tstamp_ref,
                         take_ref, esid_ref, ets_ref, epop_ref, eact_ref,
                         evals_ref, wit_ref,
@@ -268,36 +314,22 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
                         *, batch: int, layout: RegLayout, n_rows: int,
                         prog_len: int):
     Q = prio_ref.shape[1]
-    F = out_tbl_ref.shape[1]
-    C = qvals_ref.shape[1]
+    F = sub_ref.shape[0] - 1
     W = batch * F
-    n_pad = out_tbl_ref.shape[0]
+    n_pad = sub_ref.shape[1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, batch), 1)
-    row_b = jax.lax.broadcasted_iota(jnp.int32, (batch, C), 0)
-    row_bf = jax.lax.broadcasted_iota(jnp.int32, (batch, F), 0)
-    row_wc = jax.lax.broadcasted_iota(jnp.int32, (W, C), 0)
-    row_w1 = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
-    lane_f = jax.lax.broadcasted_iota(jnp.int32, (1, F), 1)
-    iota_col = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0)
-    n_iota_col = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)
     valid = valid_ref[:] != 0
     seq = seq_ref[:]
     tenant = tenant_ref[:]
     w = w_ref[:]
-    sid = sid_ref[:]
-    ts = ts_ref[:]
-    vals_bits = jax.lax.bitcast_convert_type(qvals_ref[:], jnp.int32)
-    out_tbl = out_tbl_ref[:]
-    act_col = act_ref[:]
     key0 = jnp.where(valid, prio_ref[:], INT_MAX)
     tag0 = jnp.where(qlive_ref[:] != 0, 0, INT_MAX)
 
-    # ---- stage 1a: selection pop (the sched_pop loop) + per-winner
-    # subscriber-row / active-flag gathers, one winner per step ----------
+    # ---- stage 1a: selection pop (the sched_pop loop), carrying only
+    # what decides the next winner ------------------------------------
     def step(b, carry):
-        (k1, tag, taken, take, psid, pts, ppop, pact, pvals,
-         wi_t, wi_tcol, wi_src, wi_ts, wi_vb) = carry
+        k1, tag, taken, take, pop = carry
         m1 = jnp.min(k1)
         c1 = k1 == m1
         m2 = jnp.min(jnp.where(c1, tag, INT_MAX))
@@ -319,59 +351,42 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
         tag = jnp.where(onehot, INT_MAX, tag)
         k1 = jnp.where(onehot, INT_MAX, k1)
         taken = jnp.where(onehot, 1, taken)
-        # winner payload gathers (masked one-hot sums, exact in bits)
-        sid_i = jnp.sum(jnp.where(onehot, sid, 0))
-        ts_i = jnp.sum(jnp.where(onehot, ts, 0))
-        vals_i = jnp.sum(jnp.where(iota_col == i, vals_bits, 0),
-                         axis=0, keepdims=True)            # (1, C) bits
-        # stage-1 expansion for this winner: subscriber row + active
-        row_i = jnp.clip(sid_i, 0, n_rows - 1)
-        oh_n = n_iota_col == row_i
-        act_i = jnp.sum(jnp.where(oh_n, act_col, 0))
-        trow = jnp.sum(jnp.where(oh_n, out_tbl, 0),
-                       axis=0, keepdims=True)              # (1, F)
-        e_valid = was_valid & (act_i != 0)
-        trow = jnp.where(e_valid & (trow >= 0), trow, -1)
         col = iota_b == b
         take = jnp.where(col, i, take)
-        psid = jnp.where(col, sid_i, psid)
-        pts = jnp.where(col, ts_i, pts)
-        ppop = jnp.where(col, was_valid.astype(jnp.int32), ppop)
-        pact = jnp.where(col, (act_i != 0).astype(jnp.int32), pact)
-        pvals = jnp.where(row_b == b, vals_i, pvals)
-        wi_t = jnp.where(row_bf == b, trow, wi_t)
-        # work-item planes: rows b*F .. b*F+F-1 carry this winner (the
-        # target column is filled lane by lane: no (B, F) -> (W, 1) reshape)
-        for f in range(F):
-            t_f = jnp.sum(jnp.where(lane_f == f, trow, 0))
-            wi_tcol = jnp.where(row_w1 == b * F + f, t_f, wi_tcol)
-        in_b = (row_w1 >= b * F) & (row_w1 < (b + 1) * F)
-        wi_src = jnp.where(in_b, sid_i, wi_src)
-        wi_ts = jnp.where(in_b, ts_i, wi_ts)
-        in_bc = (row_wc >= b * F) & (row_wc < (b + 1) * F)
-        wi_vb = jnp.where(in_bc, vals_i, wi_vb)
-        return (k1, tag, taken, take, psid, pts, ppop, pact, pvals,
-                wi_t, wi_tcol, wi_src, wi_ts, wi_vb)
+        pop = jnp.where(col, was_valid.astype(jnp.int32), pop)
+        return k1, tag, taken, take, pop
 
     zero_b = jnp.zeros((1, batch), jnp.int32)
-    carry = (key0, tag0, jnp.zeros((1, Q), jnp.int32),
-             zero_b, zero_b, zero_b, zero_b, zero_b,
-             jnp.zeros((batch, C), jnp.int32),
-             jnp.zeros((batch, F), jnp.int32),
-             jnp.zeros((W, 1), jnp.int32),
-             jnp.zeros((W, 1), jnp.int32),
-             jnp.zeros((W, 1), jnp.int32),
-             jnp.zeros((W, C), jnp.int32))
-    (_, _, _, take, psid, pts, ppop, pact, pvals,
-     wi_t, wit_col, wi_src, wi_ts, wi_vb) = jax.lax.fori_loop(
-        0, batch, step, carry)
+    _, _, _, take, pop = jax.lax.fori_loop(
+        0, batch, step,
+        (key0, tag0, jnp.zeros((1, Q), jnp.int32), zero_b, zero_b))
+
+    # ---- stage 1b: every winner's payload (sid, ts, value bits),
+    # subscriber row (out_table's F columns) and active flag, once ------
+    pay = _lane_gather(_to_col(take), pay_ref)
+    e_sid, e_ts = pay[0], pay[1]
+    e_vbits = jnp.concatenate(pay[2:], axis=1)             # (batch, C)
+    sub = _lane_gather(jnp.clip(e_sid, 0, n_rows - 1), sub_ref)
+    e_act = sub[F] != 0
+    e_valid = (_to_col(pop) != 0) & e_act
+    trow = jnp.concatenate(sub[:F], axis=1)                # (batch, F)
+    wi_t = jnp.where(e_valid & (trow >= 0), trow, -1)
+    # work items: rows b*F .. b*F+F-1 carry winner b (one-hot expansion:
+    # no (B, F) -> (W, 1) reshape)
+    w_row = jax.lax.broadcasted_iota(jnp.int32, (W, batch), 0)
+    b_first = jax.lax.broadcasted_iota(jnp.int32, (W, batch), 1) * F
+    wi = _gather_i32(((w_row >= b_first) & (w_row < b_first + F))
+                     .astype(jnp.float32),
+                     jnp.concatenate(pay, axis=1))         # (W, 2 + C)
+    wit_col = sum(_gather_i32((w_row == b_first + f).astype(jnp.float32),
+                              wi_t[:, f:f + 1]) for f in range(F))
 
     take_ref[:] = take
-    esid_ref[:] = psid
-    ets_ref[:] = pts
-    epop_ref[:] = ppop
-    eact_ref[:] = pact
-    evals_ref[:] = jax.lax.bitcast_convert_type(pvals, jnp.float32)
+    esid_ref[:] = e_sid
+    ets_ref[:] = e_ts
+    epop_ref[:] = pop
+    eact_ref[:] = e_act.astype(jnp.int32)
+    evals_ref[:] = jax.lax.bitcast_convert_type(e_vbits, jnp.float32)
     wit_ref[:] = wi_t
 
     # ---- stages 2 + 3 in the same kernel: winners never left VMEM ------
@@ -379,10 +394,10 @@ def _fused_round_kernel(prio_ref, seq_ref, valid_ref, qlive_ref, tenant_ref,
     _pack_apply_outputs(
         _apply_body(layout, n_rows, prog_len,
                     in_tbl_ref[:], progs_ref[:], consts_ref[:],
-                    comp_ref[:], act_col, values_ref[:], tstamp_ref[:],
-                    rows_col, rows_col, wi_src,
-                    jax.lax.bitcast_convert_type(wi_vb, jnp.float32),
-                    wi_ts, wit_col >= 0),
+                    comp_ref[:], act_ref[:], values_ref[:], tstamp_ref[:],
+                    rows_col, rows_col, wi[:, 0:1],
+                    jax.lax.bitcast_convert_type(wi[:, 2:], jnp.float32),
+                    wi[:, 1:2], wit_col >= 0),
         (nv_ref, tso_ref, live_ref, keep_ref, kts_ref, pf_ref, bad_ref))
 
 
@@ -425,15 +440,23 @@ def fused_round_call(prio_slot, seq, valid, t_slot, w_slot, sid, vals, ts,
 
     qlive = qrow(jnp.ones((Q,), jnp.int32))
     i32b = jnp.int32
+    # tables the winners are gathered from, laid out as rows: the queue's
+    # sid, ts and value bits by channel; out_table's F columns and active
+    pay = jnp.concatenate(
+        [jnp.asarray(sid, i32b)[None], jnp.asarray(ts, i32b)[None],
+         jax.lax.bitcast_convert_type(vals.astype(jnp.float32), i32b).T])
+    sub = ntbl(jnp.concatenate([jnp.asarray(out_table, i32b),
+                                jnp.asarray(active, i32b)[:, None]], axis=1),
+               i32b).T
     outs = pl.pallas_call(
         functools.partial(_fused_round_kernel, batch=batch, layout=layout,
                           n_rows=N, prog_len=L),
         out_shape=(
             jax.ShapeDtypeStruct((1, batch), i32b),       # take
-            jax.ShapeDtypeStruct((1, batch), i32b),       # e_sid
-            jax.ShapeDtypeStruct((1, batch), i32b),       # e_ts
+            jax.ShapeDtypeStruct((batch, 1), i32b),       # e_sid
+            jax.ShapeDtypeStruct((batch, 1), i32b),       # e_ts
             jax.ShapeDtypeStruct((1, batch), i32b),       # e_pop
-            jax.ShapeDtypeStruct((1, batch), i32b),       # e_act
+            jax.ShapeDtypeStruct((batch, 1), i32b),       # e_act
             jax.ShapeDtypeStruct((batch, C), jnp.float32),  # e_vals
             jax.ShapeDtypeStruct((batch, F), i32b),       # wi_t
             jax.ShapeDtypeStruct((W, C), jnp.float32),    # new_vals
@@ -448,9 +471,8 @@ def fused_round_call(prio_slot, seq, valid, t_slot, w_slot, sid, vals, ts,
         name="fused_round",
         interpret=interpret,
     )(qrow(prio_slot), qrow(seq), qrow(valid), qlive, qrow(t_slot),
-      qrow(w_slot), qrow(sid), qrow(ts),
-      jnp.pad(vals.astype(jnp.float32), ((0, Qp - Q), (0, 0))),
-      ntbl(out_table, i32b), ntbl(in_table, i32b),
+      qrow(w_slot), jnp.pad(pay, ((0, 0), (0, Qp - Q))), sub,
+      ntbl(in_table, i32b),
       ntbl(progs, i32b).reshape(Np, L * 4),
       ntbl(consts, jnp.float32),
       ncol(is_composite), ncol(active),

@@ -215,9 +215,18 @@ def _bits_equal(name, a, b):
         err_msg=name)
 
 
-@pytest.mark.parametrize("Q,N,C,B,F,M,L", [(32, 16, 1, 2, 2, 2, 4),
-                                           (64, 24, 3, 4, 5, 6, 10),
-                                           (200, 40, 4, 8, 3, 4, 12)])
+@pytest.mark.parametrize("Q,N,C,B,F,M,L", [
+    (32, 16, 1, 2, 2, 2, 4), (64, 24, 3, 4, 5, 6, 10),
+    (200, 40, 4, 8, 3, 4, 12),
+    # batch == Q: the valid slots run out mid-loop and every slot is
+    # popped, so the inf/NaN/-0.0 payloads and (N = 8) a third of the
+    # sids, clipped to the last row, reach the gathers
+    (128, 8, 4, 128, 2, 2, 6),
+    # batch above the ~58 valid slots, one subscriber per row
+    (96, 12, 2, 80, 1, 3, 8),
+    # queue and row tables that are not a whole number of the gathers'
+    # 512-lane chunks (2,304 = 4 * 512 + 256 slots, 640 = 512 + 128 rows)
+    (2300, 600, 4, 64, 3, 2, 8)])
 def test_fused_round_kernel_sweep(Q, N, C, B, F, M, L):
     rfk, rfr = _rf_modules()
     K, T = 8, 4
